@@ -59,7 +59,7 @@ import re
 import struct
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Optional
 
 from ..obs import metrics as obs_metrics
@@ -68,7 +68,7 @@ from ..utils.checksum import frame_checksum
 log = logging.getLogger(__name__)
 
 #: on-disk container format revision — bump on any layout change
-FORMAT_VERSION = 1
+FORMAT_VERSION = 2
 #: engine kernel-semantics revision — bump whenever a kernel's compiled
 #: behavior changes without its cache key changing (an executable compiled
 #: by the old engine would silently compute the OLD semantics)
@@ -643,57 +643,48 @@ _LOAD_LOCK = threading.Lock()
 
 
 def default_dir() -> str:
-    base = os.environ.get(
-        "SPARK_RAPIDS_TPU_COMPILE_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "spark_rapids_tpu"),
-    )
-    try:
-        return os.path.join(base, "xc-" + fence()["backend"])
-    except Exception:  # noqa: BLE001
-        return os.path.join(base, "xc")
+    """``xc-<backend>`` under the root both compile caches share
+    (kernels.compile_cache_root)."""
+    from ..kernels import compile_cache_root
+
+    return os.path.join(compile_cache_root(), "xc-" + fence()["backend"])
 
 
 def configure(conf) -> Optional[XlaStore]:
     """(Re)build the process-global store from the session conf. Sessions
     share one store (like the kernel cache the store backs); reconfiguring
-    with the same settings is a no-op. Never raises — a store that cannot
-    be set up leaves the engine on plain first-touch compiles."""
+    with the same settings is a no-op. A store that was asked for and
+    cannot be set up raises."""
     global _STORE
     from .. import config as cfg
 
-    try:
-        enabled = cfg.COMPILE_CACHE_ENABLED.get(conf)
-        if (
-            os.environ.get("SPARK_RAPIDS_TPU_NO_PERSISTENT_CACHE")
-            and conf.get_raw(cfg.COMPILE_CACHE_ENABLED.key) is None
-        ):
-            # the test-env escape hatch (tests/conftest.py) keeps implicit
-            # caching off; an EXPLICIT conf still wins — that is how the
-            # store's own tests opt in
-            enabled = False
-        if not enabled:
-            with _STORE_LOCK:
-                _STORE = None
-            return None
-        root = cfg.COMPILE_CACHE_DIR.get(conf) or default_dir()
-        max_bytes = cfg.COMPILE_CACHE_MAX_BYTES.get(conf)
-        lock_timeout = cfg.COMPILE_CACHE_LOCK_TIMEOUT_S.get(conf)
-        with _STORE_LOCK:
-            s = _STORE
-            if (
-                s is not None
-                and s.root == root
-                and s.max_bytes == max_bytes
-                and s.lock_timeout_s == lock_timeout
-            ):
-                return s
-            _STORE = XlaStore(root, max_bytes, lock_timeout)
-            return _STORE
-    except Exception as e:  # noqa: BLE001 - optimization, never fatal
-        log.warning("compile cache disabled (setup failed): %s", e)
+    enabled = cfg.COMPILE_CACHE_ENABLED.get(conf)
+    if (
+        os.environ.get("SPARK_RAPIDS_TPU_NO_PERSISTENT_CACHE")
+        and conf.get_raw(cfg.COMPILE_CACHE_ENABLED.key) is None
+    ):
+        # the test-env escape hatch (tests/conftest.py) keeps implicit
+        # caching off; an EXPLICIT conf still wins — that is how the
+        # store's own tests opt in
+        enabled = False
+    if not enabled:
         with _STORE_LOCK:
             _STORE = None
         return None
+    root = cfg.COMPILE_CACHE_DIR.get(conf) or default_dir()
+    max_bytes = cfg.COMPILE_CACHE_MAX_BYTES.get(conf)
+    lock_timeout = cfg.COMPILE_CACHE_LOCK_TIMEOUT_S.get(conf)
+    with _STORE_LOCK:
+        s = _STORE
+        if (
+            s is not None
+            and s.root == root
+            and s.max_bytes == max_bytes
+            and s.lock_timeout_s == lock_timeout
+        ):
+            return s
+        _STORE = XlaStore(root, max_bytes, lock_timeout)
+        return _STORE
 
 
 def active_store() -> Optional[XlaStore]:
@@ -773,13 +764,19 @@ def load_executable(digest: Optional[str]):
 def _deserialize(payload: bytes):
     import pickle
 
+    import jax
     from jax.experimental import serialize_executable as _se
 
-    ser, in_tree, out_tree = pickle.loads(payload)
-    if fence()["backend"] == "cpu":
-        with _LOAD_LOCK:
-            return _se.deserialize_and_load(ser, in_tree, out_tree)
-    return _se.deserialize_and_load(ser, in_tree, out_tree)
+    ser, in_tree, out_tree, device_ids = pickle.loads(payload)
+    # load onto the devices the executable was compiled for: the default is
+    # every device of the backend, which a one-device program cannot take
+    by_id = {d.id: d for d in jax.devices()}
+    devices = [by_id[i] for i in device_ids]
+    cpu = fence()["backend"] == "cpu"
+    with _LOAD_LOCK if cpu else nullcontext():
+        return _se.deserialize_and_load(
+            ser, in_tree, out_tree, execution_devices=devices
+        )
 
 
 def serialize_executable(compiled) -> Optional[bytes]:
@@ -793,8 +790,12 @@ def serialize_executable(compiled) -> Optional[bytes]:
         from jax.experimental import serialize_executable as _se
 
         ser, in_tree, out_tree = _se.serialize(compiled)
+        device_ids = [
+            d.id for d in compiled.runtime_executable().local_devices()
+        ]
         return pickle.dumps(
-            (ser, in_tree, out_tree), protocol=pickle.HIGHEST_PROTOCOL
+            (ser, in_tree, out_tree, device_ids),
+            protocol=pickle.HIGHEST_PROTOCOL,
         )
     except Exception as e:  # noqa: BLE001 - skip persisting, keep serving
         log.debug("executable not serializable (ignored): %s", str(e)[:200])

@@ -861,8 +861,8 @@ COMPILE_CACHE_ENABLED = conf("spark.rapids.tpu.compileCache.enabled").doc(
 
 COMPILE_CACHE_DIR = conf("spark.rapids.tpu.compileCache.dir").doc(
     "Directory for the executable store. Empty (default) auto-selects "
-    "~/.cache/spark_rapids_tpu/xc-<backend> (or "
-    "$SPARK_RAPIDS_TPU_COMPILE_CACHE/xc-<backend>). Point every server "
+    "$JAX_COMPILATION_CACHE_DIR/xc-<backend> when that variable is set, "
+    "else .cache/xc-<backend> inside the checkout. Point every server "
     "of a fleet at ONE shared directory: a per-entry file lock makes the "
     "fleet compile each shape once (docs/operations.md restart runbook)."
 ).string_conf(None)

@@ -8,7 +8,7 @@ compiles into ONE program over the coalesced partition batch —
 1. radix-encode partition + order keys, one variadic stable sort;
 2. segment/peer boundaries by adjacent word difference;
 3. every window function lowers onto *segmented scans*
-   (``lax.associative_scan`` with a reset flag) and gathers:
+   (ops/scan.py segscan: a log-depth scan with a reset flag) and gathers:
    running/unbounded frames = inclusive scan (+ gather at segment/peer end),
    bounded sum/count/avg = prefix-sum differences at clamped indices,
    bounded min/max = sparse-table range queries (doubling RMQ),
@@ -43,23 +43,11 @@ from ..expr.windows import (
 )
 from ..ops.concat import concat_device
 from ..ops.gather import gather_batch
+from ..ops.scan import segscan as _segscan
 from ..ops.sortkeys import column_radix_words, sort_permutation
 from ..plan.physical import Exec, ExecContext, PartitionSet
 from ..types import Schema, StringType, StructField
 from .tpu import val_to_column
-
-def _segscan(vals, starts, op):
-    """Inclusive segmented scan: op-accumulate left-to-right, reset where
-    ``starts``. Standard (flag, value) associative combine."""
-
-    def comb(a, b):
-        af, av = a
-        bf, bv = b
-        return (af | bf, jnp.where(bf, bv, op(av, bv)))
-
-    _, v = jax.lax.associative_scan(comb, (starts, vals))
-    return v
-
 
 def _seg_last_idx(idx, starts, cap):
     """Per-row index of its segment's last row (reverse segmented max)."""

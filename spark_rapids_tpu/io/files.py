@@ -480,7 +480,18 @@ class CpuFileScanExec(Exec):
             parts = [lambda: iter(())]
         return PartitionSet(parts)
 
-    def _execute_coalescing(self, pairs) -> PartitionSet:
+    @property
+    def num_partitions(self) -> int:
+        """Upper bound on the partitions ``execute`` produces, counted
+        before pruning (pruning only removes files, and counts them). The
+        planner's merge-exchange decisions hang on one-vs-many
+        (plan/planner.py _num_partitions_hint)."""
+        pairs = list(zip(self.files, self._part_values))
+        if self.reader_type == "COALESCING":
+            return max(1, len(self._coalesced_groups(pairs)))
+        return max(1, len(pairs))
+
+    def _coalesced_groups(self, pairs) -> List[List[tuple]]:
         """Small files grouped by on-disk size into shared partitions until
         the reader byte target (MultiFileParquetPartitionReader's stitching,
         at file granularity)."""
@@ -499,6 +510,10 @@ class CpuFileScanExec(Exec):
             cur_bytes += sz
         if cur:
             groups.append(cur)
+        return groups
+
+    def _execute_coalescing(self, pairs) -> PartitionSet:
+        groups = self._coalesced_groups(pairs)
 
         def make(group):
             def it():

@@ -2,11 +2,11 @@
 (reference: GpuHashJoin.scala:165-362, cudf hash joins).
 
 TPU-first design: no hash table. Both sides' keys are radix-encoded
-(ops/sortkeys) and matched with a **merge-join via concatenated variadic
-sort**: sorting [build ++ probe] keys with a side-flag tiebreak yields, for
-every probe row, the count of build keys strictly-less (lower bound) or
+(ops/sortkeys) and matched with a **merge-join via concatenated sort**:
+sorting [build ++ probe] keys with a side-flag tiebreak yields, for every
+probe row, the count of build keys strictly-less (lower bound) or
 less-or-equal (upper bound) — exact lexicographic multi-word matching with
-two fused ``lax.sort`` calls, no collisions, static shapes.
+two stable sorts, no collisions, static shapes.
 
 Join semantics (Spark): NULL keys never match (side-specific sentinel words
 make them unequal to everything); NaN keys match each other and -0.0 == 0.0
@@ -23,7 +23,7 @@ import jax.numpy as jnp
 
 from ..columnar.device import DeviceBatch, DeviceColumn
 from ..ops.aggregate import _normalize_float
-from ..ops.sortkeys import column_radix_words
+from ..ops.sortkeys import column_radix_words, sort_permutation
 from ..types import StringType
 
 
@@ -91,10 +91,10 @@ def join_bounds(
     bw, _ = _key_words(build_cols, build_live, 0)
     pw, _ = _key_words(probe_cols, probe_live, 1)
 
-    # build sort order (for the gather phase)
-    biota = jnp.arange(nb, dtype=jnp.int32)
-    build_sorted = jax.lax.sort(tuple(bw) + (biota,), num_keys=len(bw), is_stable=True)
-    build_order = build_sorted[-1]
+    # build sort order (for the gather phase). Every sort here goes through
+    # sort_permutation's single-key passes: one variadic lax.sort over the
+    # key words costs the TPU compiler minutes at these sizes
+    build_order = sort_permutation(bw, None, live_first=False)
 
     def bound(probe_first: bool):
         # concatenated sort: side flag breaks ties; count build rows before
@@ -106,10 +106,10 @@ def join_bounds(
         src = jnp.concatenate(
             [jnp.full(nb, -1, jnp.int32), jnp.arange(npr, dtype=jnp.int32)]
         )
-        out = jax.lax.sort(
-            tuple(keys) + (flags, src), num_keys=len(keys) + 1, is_stable=True
+        perm = sort_permutation(
+            keys + [flags.astype(jnp.uint64)], None, live_first=False
         )
-        sflags, ssrc = out[-2], out[-1]
+        sflags, ssrc = flags[perm], src[perm]
         is_build = (
             (sflags == 0) if not probe_first else (sflags == 1)
         )

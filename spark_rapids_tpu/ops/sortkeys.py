@@ -156,21 +156,24 @@ def sort_permutation(
     ``lax.sort`` over k words compiled in O(minutes) at 2^16+ rows, while
     this form embeds exactly ONE two-operand sort in the program regardless
     of key count (the scan reuses it per word), with identical ordering
-    semantics (stable passes ⇒ lexicographic).
+    semantics (stable passes ⇒ lexicographic). Each 64-bit word goes as two
+    32-bit passes: 64-bit types are emulated on the TPU, and the one
+    embedded sort compiles about three times faster on a uint32 key.
     """
     cap = words[0].shape[0]
-    keys = []
+    halves = []  # most-significant first
     if live_first:
-        keys.append(jnp.where(row_mask, jnp.uint64(0), jnp.uint64(1)))
-    keys.extend(words)
-    iota = jnp.arange(cap, dtype=jnp.int32)
-    if len(keys) == 1:
-        _, perm = jax.lax.sort((keys[0], iota), num_keys=1, is_stable=True)
-        return perm
-    stacked = jnp.stack(keys[::-1])  # least-significant word first
+        halves.append(jnp.where(row_mask, jnp.uint32(0), jnp.uint32(1)))
+    for w in words:
+        w = w.astype(jnp.uint64)
+        halves.append((w >> jnp.uint64(32)).astype(jnp.uint32))
+        halves.append(w.astype(jnp.uint32))
+    stacked = jnp.stack(halves[::-1])  # least-significant half first
     # inherit the data's varying-axis type so the scan carry matches inside
     # shard_map (a plain iota is replicated; the sorted perm is varying)
-    iota = iota + (stacked[0] * jnp.uint64(0)).astype(jnp.int32)
+    iota = jnp.arange(cap, dtype=jnp.int32) + (stacked[0] * jnp.uint32(0)).astype(
+        jnp.int32
+    )
 
     def one_pass(perm, w):
         _, perm = jax.lax.sort((w[perm], perm), num_keys=1, is_stable=True)

@@ -20,7 +20,7 @@ from typing import Callable, List, Sequence
 
 import jax
 import jax.numpy as jnp
-from .compat import shard_map  # jax.shard_map / experimental, shimmed
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..columnar.device import DeviceBatch, DeviceColumn

@@ -78,18 +78,15 @@ class ExecContext:
             self.catalog.device_limit = limit
         else:
             # size the spillable budget from device memory × allocFraction
-            # (GpuDeviceManager.initializeRmm's pool sizing)
-            try:
-                import jax
+            # (GpuDeviceManager.initializeRmm's pool sizing); the CPU
+            # backend reports no limit: unlimited, spill-on-demand
+            from ..mem import device_bytes_limit
 
-                stats = jax.local_devices()[0].memory_stats() or {}
-                total = stats.get("bytes_limit", 0)
-                if total:
-                    self.catalog.device_limit = int(
-                        total * cfg.POOL_SIZE_FRACTION.get(conf)
-                    )
-            except Exception:
-                pass  # CPU backend / no stats: unlimited, spill-on-demand
+            total = device_bytes_limit()
+            if total:
+                self.catalog.device_limit = int(
+                    total * cfg.POOL_SIZE_FRACTION.get(conf)
+                )
         import itertools
 
         import threading

@@ -291,7 +291,9 @@ def _num_partitions_hint(e: Exec) -> int:
     from ..exec.cpu import CpuRangeExec
     from ..exec.cpu_join import CpuCartesianProductExec
 
-    if isinstance(e, (CpuScanExec, CpuRangeExec)):
+    from ..io.files import CpuFileScanExec
+
+    if isinstance(e, (CpuScanExec, CpuRangeExec, CpuFileScanExec)):
         return e.num_partitions
     if isinstance(e, CpuShuffleExchangeExec):
         return e.num_partitions

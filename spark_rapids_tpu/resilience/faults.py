@@ -145,7 +145,7 @@ class FaultInjector:
             self._record("kernel_compile")
             raise InjectedFault(
                 "compile",
-                "UNAVAILABLE: injected remote_compile failure (fault injection)",
+                "UNAVAILABLE: injected compile failure (fault injection)",
             )
 
     def on_spill_write(self) -> None:

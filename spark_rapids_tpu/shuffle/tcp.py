@@ -54,7 +54,7 @@ _M_CORRUPT = _obs_registry.counter("shuffle.corruptFrames")
 # RapidsShuffleClientSuite.scala). One-way latency is added per frame and
 # bandwidth caps serialize inside the socket write lock, so concurrent
 # senders contend for the simulated link exactly like a real NIC.
-# Env (read at import so executor subprocesses inherit):
+# Env (read at import so executor processes inherit):
 #   SRT_TCP_INJECT_LATENCY_MS  — one-way per-frame latency
 #   SRT_TCP_INJECT_BW_MBPS     — link bandwidth cap (payload MB/s)
 import os as _os

@@ -285,6 +285,28 @@ def test_multithreaded_reader(tmp_path):
     assert_cpu_and_tpu_equal(build)
 
 
+@pytest.mark.parametrize("reader", ["PERFILE", "MULTITHREADED", "COALESCING"])
+def test_aggregate_over_multi_partition_file_scan(reader, tmp_path):
+    """A reader that yields one partition per file must get the merge
+    exchange: the planner once took every file scan for ONE partition and
+    aggregated each file on its own (eight partial counts as the answer)."""
+    from spark_rapids_tpu.functions import count
+
+    t = _data(400, seed=8)
+    path = str(tmp_path / "agg")
+    cpu_session().create_dataframe(t, num_partitions=4).write.mode(
+        "overwrite"
+    ).parquet(path)
+    for sess in (cpu_session(), tpu_session()):
+        got = (
+            sess.read.option("readerType", reader)
+            .parquet(path)
+            .agg(count("*").alias("n"))
+            .collect()
+        )
+        assert got == [(400,)], (reader, got)
+
+
 # ── format specifics ───────────────────────────────────────────────────────
 def test_csv_schema_option(tmp_path):
     from spark_rapids_tpu.types import Schema, StructField

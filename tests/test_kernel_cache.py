@@ -85,3 +85,44 @@ def test_sort_and_join_kernels_cached():
     q().collect()
     assert K.build_count() == builds0
     assert K.trace_count() == traces0
+
+
+def _enable_cache_recording(monkeypatch):
+    """Run enable_persistent_cache with the test environment's kill switch
+    lifted, recording (not applying) what it would set on jax."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    updates = {}
+    monkeypatch.delenv("SPARK_RAPIDS_TPU_NO_PERSISTENT_CACHE")
+    monkeypatch.setattr(K, "_PERSISTENT_ENABLED", False)
+    monkeypatch.setattr(jax.config, "update", updates.__setitem__)
+    monkeypatch.setattr(cc, "reset_cache", lambda: None)
+    K.enable_persistent_cache()
+    return updates
+
+
+def test_cache_placement_follows_jax_env(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: jax's cache stays where the variable
+    put it (no directory set in code) and the store resolves under it."""
+    from spark_rapids_tpu.cache import xla_store as xc
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    updates = _enable_cache_recording(monkeypatch)
+    assert "jax_compilation_cache_dir" not in updates
+    assert xc.default_dir() == str(tmp_path / ("xc-" + xc.fence()["backend"]))
+
+
+def test_cache_placement_defaults_inside_checkout(monkeypatch):
+    """Unset: both caches resolve to the fixed .cache/ of the checkout."""
+    import os
+
+    from spark_rapids_tpu.cache import xla_store as xc
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".cache"
+    )
+    updates = _enable_cache_recording(monkeypatch)
+    assert updates["jax_compilation_cache_dir"] == os.path.join(root, "jax")
+    assert xc.default_dir() == os.path.join(root, "xc-" + xc.fence()["backend"])

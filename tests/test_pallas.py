@@ -1,6 +1,7 @@
 """Pallas string kernels (ops/pallas_strings.py) — differential against the
-python oracle and the XLA window-gather path. On the CPU test backend the
-kernel runs in interpret mode; on TPU it compiles through Mosaic."""
+python oracle and the XLA window-gather path. These tests pass
+``interpret=True``; tests/test_chip_compile.py compiles the kernel through
+Mosaic for the chip."""
 from __future__ import annotations
 
 import numpy as np
@@ -93,12 +94,12 @@ def test_engine_dispatch_reaches_pallas(monkeypatch):
     calls = {"n": 0}
     real = PS.match_starts
 
-    def spy(data, lengths, pat, interpret=False):
+    def spy(data, lengths, pat):
+        # the engine never asks for the interpreter; this CPU test does
         calls["n"] += 1
-        return real(data, lengths, pat, interpret=interpret)
+        return real(data, lengths, pat, interpret=True)
 
     monkeypatch.setattr(PS, "_backend_is_tpu", lambda: True)
-    monkeypatch.setattr(PS, "_mosaic_probe_ok", lambda: True)
     monkeypatch.setattr(PS, "match_starts", spy)
     from harness import cpu_session, tpu_session
 
@@ -138,7 +139,6 @@ def test_gate_off_uses_xla(monkeypatch):
     import jax.numpy as jnp
 
     monkeypatch.setattr(PS, "_backend_is_tpu", lambda: True)
-    monkeypatch.setattr(PS, "_mosaic_probe_ok", lambda: True)
     assert not PS.usable_for(jnp.zeros((4, 8), jnp.uint8))  # narrow plane
     assert PS.usable_for(jnp.zeros((4, 128), jnp.uint8))
     PS.set_enabled(False)
@@ -146,3 +146,22 @@ def test_gate_off_uses_xla(monkeypatch):
         assert not PS.usable_for(jnp.zeros((4, 8), jnp.uint8))
     finally:
         PS.set_enabled(True)
+
+
+def test_mosaic_compile_error_propagates():
+    """A Mosaic compile failure raises to the caller: no re-trace through
+    the XLA lowering, no switch flipped for the process."""
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu import kernels as K
+
+    traces = []
+
+    def fn(x):
+        traces.append(1)
+        raise RuntimeError("Mosaic failed to compile TPU kernel: forced")
+
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        K.GuardedJit(fn)(jnp.zeros(4))
+    assert len(traces) == 1
+    assert PS.ENABLED

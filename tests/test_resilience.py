@@ -74,9 +74,9 @@ def test_oom_classified_through_cause_chain():
 
 
 def test_oom_classified_through_real_jax_wrappers():
-    """jax re-wraps backend errors (JaxRuntimeError around XlaRuntimeError);
-    both layers must classify through the chain."""
-    from jaxlib.xla_extension import XlaRuntimeError
+    """A backend error (jax.errors.JaxRuntimeError on the installed jax)
+    re-raised inside another exception must classify through the chain."""
+    from jax.errors import JaxRuntimeError as XlaRuntimeError
 
     xla = XlaRuntimeError("RESOURCE_EXHAUSTED: out of memory while allocating")
     try:

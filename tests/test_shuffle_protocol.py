@@ -262,20 +262,9 @@ def test_manager_over_tcp_transport():
 # ── ICI device plane ───────────────────────────────────────────────────────
 
 
-def _require_shard_map():
-    from spark_rapids_tpu.parallel.compat import (
-        HAS_SHARD_MAP,
-        SHARD_MAP_UNAVAILABLE_MSG,
-    )
-
-    if not HAS_SHARD_MAP:
-        pytest.skip(SHARD_MAP_UNAVAILABLE_MSG)
-
-
 def test_ici_all_to_all_exchange():
     import jax
 
-    _require_shard_map()
     from spark_rapids_tpu.parallel.distributed import make_mesh
     from spark_rapids_tpu.parallel.ici import (
         batch_to_global_leaves,
@@ -529,7 +518,6 @@ def test_ici_exchange_skew_escalates_capacity():
     (reference: windowed sends never drop data — BufferSendState.scala)."""
     import jax
 
-    _require_shard_map()
     from jax.sharding import Mesh
     from spark_rapids_tpu.parallel.ici import ici_exchange
     from spark_rapids_tpu.columnar.device import host_to_device
