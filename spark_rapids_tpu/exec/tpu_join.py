@@ -22,7 +22,7 @@ from ..columnar.device import DeviceBatch, DeviceColumn, bucket_capacity, dc_rep
 from ..expr import Expression, bind
 from ..expr.base import Ctx, Val
 from ..ops.concat import concat_device
-from ..ops.gather import compact, gather_column
+from ..ops.gather import compact, gather_column, shrink_one
 from ..ops.join import gather_pairs, join_bounds, join_output_schema, pad_string_column
 from ..plan.physical import Exec, ExecContext, PartitionSet
 from ..types import Schema, StringType, StructField
@@ -594,12 +594,16 @@ def _colocated(anchor, arr):
 def _chunk_device_batch(db: DeviceBatch, rows: int):
     """Slice a device batch into static sub-batches of <= rows (shared by
     the nested-loop and cartesian pair loops)."""
+    if db.capacity > rows:
+        # graft: ok(host-sync: one sync per stream batch, to chunk the live
+        # rows and not the capacity — an ungrouped aggregate's one row sits
+        # in 16k slots and a pair batch's in MAX_PAIR_CAP, so a chain of
+        # cross joins (TPC-DS q28) otherwise multiplies the chunk counts of
+        # its stages)
+        db = shrink_one(db, db.row_count())
     if db.capacity <= rows:
         yield db
         return
-    # chunk over CAPACITY, not the live-row count: the count is a device
-    # scalar and syncing it stalls dispatch; padded capacity is at
-    # most ~2x the live rows, and the clip below keeps tail chunks empty-valid
     n = db.capacity
     # graft: ok(cancel-beat: slices one already-resident batch; the
     # consuming join loop beats per chunk)

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, field
+from functools import cached_property
 from typing import Optional, Sequence, Tuple
 
 from ..expr import (
@@ -23,6 +24,11 @@ from ..types import BOOLEAN, DataType, LONG, Schema, StructField
 
 
 class LogicalPlan:
+    """Nodes are immutable once built: rules make new nodes
+    (``dataclasses.replace``), never assign to a field of an existing one.
+    Every subclass's ``schema`` is a ``cached_property`` on that footing, so
+    a plan's schema costs one visit per node and not a product over depth."""
+
     def children(self) -> Sequence["LogicalPlan"]:
         return []
 
@@ -88,15 +94,14 @@ class Project(LogicalPlan):
     def children(self):
         return [self.child]
 
-    @property
+    @cached_property
     def schema(self) -> Schema:
-        return Schema(
-            [
-                StructField(output_name(e), _bound(e, self.child.schema).data_type,
-                            _bound(e, self.child.schema).nullable)
-                for e in self.exprs
-            ]
-        )
+        cs = self.child.schema
+        fields = []
+        for e in self.exprs:
+            b = _bound(e, cs)
+            fields.append(StructField(output_name(e), b.data_type, b.nullable))
+        return Schema(fields)
 
     def _node_string(self):
         return f"Project [{', '.join(map(str, self.exprs))}]"
@@ -110,7 +115,7 @@ class Filter(LogicalPlan):
     def children(self):
         return [self.child]
 
-    @property
+    @cached_property
     def schema(self) -> Schema:
         return self.child.schema
 
@@ -127,11 +132,12 @@ class Aggregate(LogicalPlan):
     def children(self):
         return [self.child]
 
-    @property
+    @cached_property
     def schema(self) -> Schema:
+        cs = self.child.schema
         fields = []
         for e in self.aggregates:
-            b = _bound(e, self.child.schema)
+            b = _bound(e, cs)
             fields.append(StructField(output_name(e), b.data_type, b.nullable))
         return Schema(fields)
 
@@ -152,14 +158,15 @@ class Generate(LogicalPlan):
     def children(self):
         return [self.child]
 
-    @property
+    @cached_property
     def schema(self) -> Schema:
         from ..expr.complex import Explode
         from ..types import MapType, StructType
 
-        g: Explode = _bound(self.generator, self.child.schema)
+        cs = self.child.schema
+        g: Explode = _bound(self.generator, cs)
         ct = g.child.data_type
-        fields = list(self.child.schema.fields)
+        fields = list(cs.fields)
         i = 0
         if g.position:
             from ..types import INT
@@ -203,7 +210,7 @@ class Sort(LogicalPlan):
     def children(self):
         return [self.child]
 
-    @property
+    @cached_property
     def schema(self) -> Schema:
         return self.child.schema
 
@@ -219,7 +226,7 @@ class Limit(LogicalPlan):
     def children(self):
         return [self.child]
 
-    @property
+    @cached_property
     def schema(self) -> Schema:
         return self.child.schema
 
@@ -240,7 +247,7 @@ class Join(LogicalPlan):
     def children(self):
         return [self.left, self.right]
 
-    @property
+    @cached_property
     def schema(self) -> Schema:
         lt = list(self.left.schema.fields)
         rt = list(self.right.schema.fields)
@@ -273,7 +280,7 @@ class Expand(LogicalPlan):
     def children(self):
         return [self.child]
 
-    @property
+    @cached_property
     def schema(self) -> Schema:
         from ..types import NullType
 
@@ -304,7 +311,7 @@ class Window(LogicalPlan):
     def children(self):
         return [self.child]
 
-    @property
+    @cached_property
     def schema(self) -> Schema:
         fields = list(self.child.schema.fields)
         for name, we in self.window_cols:
@@ -325,7 +332,7 @@ class Hint(LogicalPlan):
     def children(self):
         return [self.child]
 
-    @property
+    @cached_property
     def schema(self) -> Schema:
         return self.child.schema
 
@@ -340,7 +347,7 @@ class Union(LogicalPlan):
     def children(self):
         return self.plans
 
-    @property
+    @cached_property
     def schema(self) -> Schema:
         return self.plans[0].schema
 
@@ -357,7 +364,7 @@ class Repartition(LogicalPlan):
     def children(self):
         return [self.child]
 
-    @property
+    @cached_property
     def schema(self) -> Schema:
         return self.child.schema
 
@@ -371,7 +378,7 @@ class Range(LogicalPlan):
     step: int
     num_partitions: int
 
-    @property
+    @cached_property
     def schema(self) -> Schema:
         return Schema([StructField("id", LONG, False)])
 
@@ -398,7 +405,7 @@ class InMemoryRelation(LogicalPlan):
     def children(self):
         return [self.child]
 
-    @property
+    @cached_property
     def schema(self) -> Schema:
         return self.child.schema
 
@@ -520,7 +527,7 @@ class WriteFiles(LogicalPlan):
     def children(self):
         return [self.child]
 
-    @property
+    @cached_property
     def schema(self) -> Schema:
         from ..io.writer import STATS_SCHEMA
 

@@ -198,10 +198,33 @@ def _map_nodes(n: Node, fn) -> Node:
 
 
 def _conjuncts(n: Optional[Node]) -> List[Node]:
+    """Top-level conjuncts of a predicate. A conjunct every branch of an
+    OR repeats is factored out of it, ``(a AND b) OR (a AND c)`` giving
+    ``a`` and ``b OR c`` (Spark's BooleanSimplification; exact under
+    three-valued logic): TPC-DS q13/q41/q48 write their join and
+    correlation keys that way, and a key left inside the OR never reaches
+    the join."""
     if n is None:
         return []
     if n.kind == "and":
         return _conjuncts(n.f["l"]) + _conjuncts(n.f["r"])
+    if n.kind == "or":
+        branches = [_conjuncts(d) for d in _disjuncts(n)]
+        common = [c for c in branches[0] if all(c in b for b in branches[1:])]
+        if common:
+            rest = [[c for c in b if c not in common] for b in branches]
+            if not all(rest):  # a branch was only the common part: OR is it
+                return common
+            ored = _and_all(rest[0])
+            for b in rest[1:]:
+                ored = Node("or", l=ored, r=_and_all(b))
+            return common + [ored]
+    return [n]
+
+
+def _disjuncts(n: Node) -> List[Node]:
+    if n.kind == "or":
+        return _disjuncts(n.f["l"]) + _disjuncts(n.f["r"])
     return [n]
 
 

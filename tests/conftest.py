@@ -2,6 +2,10 @@ import os
 
 # 8 virtual devices for mesh tests; must be set before jax initializes backends
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+# The tests check answers, not speed, and XLA:CPU compilation is most of the
+# suite's CPU time: LLVM at -O0 takes a third off it (ISSUE 25, step 4). An
+# environment variable and not jax.config, so that child processes get it too.
+os.environ.setdefault("JAX_DISABLE_MOST_OPTIMIZATIONS", "1")
 # The XLA-CPU executable serializer segfaults writing some window kernels
 # while worker threads execute concurrently (observed deterministically in
 # full-suite runs; compile itself is fine). The on-disk cache only buys
@@ -14,7 +18,105 @@ import jax
 # Tests run on the CPU backend; the chip is exercised by chip_smoke.py.
 jax.config.update("jax_platforms", "cpu")
 
+import faulthandler  # noqa: E402
+import signal  # noqa: E402
+import tempfile  # noqa: E402
+
 import pytest  # noqa: E402
+
+#: every test's limit, in seconds: the longest honest test takes 120 of them
+#: with six workers on eight cores, and the driver's whole run is cut at 1470
+TEST_LIMIT_S = 300
+
+
+@pytest.fixture(autouse=True)
+def _test_limit(request):
+    """Fail a test by name, with every thread's stack, once it has run
+    TEST_LIMIT_S: a hang then costs one test and not the run's clock. The
+    handler runs when the interpreter next has control, so a native call
+    that never returns is not ended by it."""
+
+    def on_alarm(signum, frame):
+        with tempfile.TemporaryFile("w+") as f:
+            faulthandler.dump_traceback(file=f, all_threads=True)
+            f.seek(0)
+            stacks = f.read()
+        pytest.fail(
+            f"{request.node.nodeid} ran past its limit of {TEST_LIMIT_S} s\n"
+            f"{stacks}",
+            pytrace=False,
+        )
+
+    previous = signal.signal(signal.SIGALRM, on_alarm)
+    signal.setitimer(signal.ITIMER_REAL, TEST_LIMIT_S)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
+
+
+def pytest_runtest_logreport(report):
+    """The suite's one self-measurement: with SRT_TEST_DURATIONS=<file>,
+    append ``nodeid, phase, seconds, outcome, worker`` (tab-separated) as
+    each phase of each test ends, so a run that is killed keeps what it
+    measured. Written by the process that gathers the reports."""
+    path = os.environ.get("SRT_TEST_DURATIONS")
+    if not path or os.environ.get("PYTEST_XDIST_WORKER"):
+        return
+    node = getattr(report, "node", None)
+    worker = node.gateway.id if node is not None else "main"
+    with open(path, "a") as f:
+        f.write(
+            f"{report.nodeid}\t{report.when}\t{report.duration:.3f}\t"
+            f"{report.outcome}\t{worker}\n"
+        )
+
+
+#: the files that take longest, in the order they go out (seconds per file:
+#: CHANGES.md, PR 25), each with the number of consecutive cases in one shard
+#: of it, 0 for a file that stays whole; every other file is one scope, after
+#: these. A file left out of here costs only balance at the run's end.
+_LONG_FILES = {
+    # whole: its 150 cases share most kernels, 288 s in one process against
+    # 1,055 core-seconds in shards of 15
+    "tests/test_qa_generated.py": 0,
+    "tests/test_tpcds.py": 9,
+    "tests/test_tpch.py": 0,
+    "tests/test_window.py": 0,
+    "tests/test_golden.py": 0,
+    "tests/test_distributed.py": 0,
+}
+
+
+@pytest.hookimpl(optionalhook=True)  # unknown under -p no:xdist
+def pytest_xdist_make_scheduler(config, log):
+    """One worker per file, the long files first. Kernels compiled for a
+    file stay in the worker that runs it until ``_bound_jit_code_size``
+    clears them at the file's end; under ``--dist load`` all six workers
+    compiled every file's kernels, and tests queued behind a long one while
+    other workers idled."""
+    from xdist.scheduler.loadscope import LoadScopeScheduling
+
+    rank = {path: i for i, path in enumerate(_LONG_FILES)}
+
+    class FileScheduling(LoadScopeScheduling):
+        def _split_scope(self, nodeid):
+            path = nodeid.split("::", 1)[0]
+            case = nodeid[nodeid.rfind("[") + 1 : -1]
+            if _LONG_FILES.get(path) and case.isdigit():
+                return f"{path}#{int(case) // _LONG_FILES[path]:02d}"
+            return path
+
+        def _assign_work_unit(self, node):
+            first = min(
+                self.workqueue,
+                key=lambda scope: rank.get(scope.split("#")[0], len(rank)),
+            )
+            self.workqueue.move_to_end(first, last=False)
+            super()._assign_work_unit(node)
+
+    return FileScheduling(config, log)
 
 
 @pytest.fixture(scope="session")

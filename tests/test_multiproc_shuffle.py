@@ -172,7 +172,9 @@ def _run_multiproc(which: str, tmp_path, extra_env=None):
     try:
         for t in threads:
             t.start()
-        deadline = _time.monotonic() + 1200
+        # under conftest's TEST_LIMIT_S, so that a child that never answers
+        # fails here, with its stderr, and not at the limit without it
+        deadline = _time.monotonic() + 240
         for t in threads[:2]:
             t.join(timeout=max(1, deadline - _time.monotonic()))
         for i, p in enumerate(procs):

@@ -167,22 +167,6 @@ def _cases():
 CASES = _cases()
 
 
-@pytest.fixture(autouse=True)
-def _bound_jit_code_within_module(request):
-    """The conftest clears compiled-kernel state per MODULE; this battery
-    alone compiles enough distinct kernels to hit the XLA:CPU JITed-code
-    segfault (see conftest._bound_jit_code_size) — clear every 20 cases."""
-    yield
-    idx = request.node.callspec.params.get("idx", 0)
-    if idx % 20 == 19:
-        import jax
-
-        from spark_rapids_tpu import kernels as K
-
-        K.clear()
-        jax.clear_caches()
-
-
 @pytest.mark.parametrize("idx", range(0, len(CASES), 1))
 def test_qa_generated(idx):
     case = CASES[idx]

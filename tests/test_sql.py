@@ -197,3 +197,32 @@ def test_unknown_names_are_loud(cpu):
         cpu.sql("select * from nonexistent")
     with pytest.raises(SqlError):
         cpu.sql("select x.o_id from orders o")
+
+
+def test_conjunct_repeated_in_every_or_branch_reaches_the_join(cpu):
+    """``(k AND a) OR (k AND b)`` plans as ``k AND (a OR b)``: TPC-DS q13,
+    q41 and q48 write their join and correlation keys inside the ORs, and a
+    key left there made the comma join a product of whole tables."""
+    written = cpu.sql(
+        "select o_id from orders, cust where "
+        "(orders.c_id = cust.c_id and city = 'city1' and amt > 80) or "
+        "(orders.c_id = cust.c_id and city = 'city2' and amt < 20) or "
+        "(orders.c_id = cust.c_id and city = 'city3') order by o_id"
+    )
+    factored = cpu.sql(
+        "select o_id from orders, cust where orders.c_id = cust.c_id and "
+        "((city = 'city1' and amt > 80) or (city = 'city2' and amt < 20) "
+        "or city = 'city3') order by o_id"
+    )
+    assert str(written._plan) == str(factored._plan)
+    assert "Join inner ['c_id='__c_id0]" in str(written._plan)
+    rows = written.collect()
+    assert rows and rows == factored.collect()
+    # a branch that is only the repeated part absorbs the others
+    absorbed = cpu.sql(
+        "select o_id from orders where amt > 90 or (amt > 90 and tag = 't1') "
+        "order by o_id"
+    )
+    assert absorbed.collect() == cpu.sql(
+        "select o_id from orders where amt > 90 order by o_id"
+    ).collect()
