@@ -3,10 +3,14 @@ aggregate merge loop, sort, and shuffle coalesce (reference:
 GpuCoalesceBatches.scala:133-455, aggregate.scala:451).
 
 Static shapes: the output capacity is the bucketed sum of input capacities
-(a trace-time constant); live rows from each input are packed at offsets
-carried as device scalars via index scatters — no host syncs. Nested
-columns (arrays/structs/maps) concatenate recursively along the row axis
-with padded-plane width alignment.
+(a trace-time constant). Live rows are a prefix of every input, so each
+input lands as one contiguous block: its dead rows are zeroed and the whole
+plane is copied (``lax.dynamic_update_slice``) to an offset carried as a
+device scalar, the running sum of ``num_rows`` — no host syncs and no index
+scatter, which this chip runs as a serial loop per element (ops/scan.py).
+Input k+1 starts over input k's zeroed tail, so the copies run in input
+order. Nested columns (arrays/structs/maps) concatenate recursively along
+the row axis with padded-plane width alignment.
 """
 from __future__ import annotations
 
@@ -35,11 +39,15 @@ def _plane_shape(cols: list[jax.Array]) -> tuple:
     )
 
 
-def _scatter_rows(dst: jax.Array, src: jax.Array, offset) -> jax.Array:
-    """Place src rows into dst starting at (traced) offset. Capacities are
-    bucketed so offset + rows can exceed dst; mode='drop' clips."""
-    idx = jnp.arange(src.shape[0], dtype=jnp.int32) + offset
-    return dst.at[idx].set(src, mode="drop")
+def _copy_rows(dst: jax.Array, src: jax.Array, offset) -> jax.Array:
+    """Copy all of src's rows into dst as one block starting at row
+    ``offset`` (traced). dynamic_update_slice would move a start back so
+    that the block fits; none is ever moved, because ``offset`` is at most
+    the capacities of the inputs before this one and dst holds the sum of
+    all of them (concat_device)."""
+    # under x64 a bare 0 is int64 and mixed index types are refused
+    start = (offset,) + (jnp.zeros((), offset.dtype),) * (src.ndim - 1)
+    return jax.lax.dynamic_update_slice(dst, src, start)
 
 
 def _concat_plane(planes: list[jax.Array], lives: list[jax.Array], offsets, cap):
@@ -50,7 +58,7 @@ def _concat_plane(planes: list[jax.Array], lives: list[jax.Array], offsets, cap)
         p = _pad_axes(p, trail)
         mask = live.reshape((-1,) + (1,) * (p.ndim - 1))
         p = jnp.where(mask, p, jnp.zeros_like(p))
-        dst = _scatter_rows(dst, p, off)
+        dst = _copy_rows(dst, p, off)
     return dst
 
 
@@ -84,22 +92,31 @@ def _col_shape_sig(c: DeviceColumn):
     )
 
 
-def concat_device(batches: list[DeviceBatch], capacity: int | None = None) -> DeviceBatch:
-    """Concatenate device batches (same schema) into one batch — ONE fused
-    jitted program per (schema, input shapes, output capacity), cached
-    module-wide; eager per-column scatters would dispatch hundreds of tiny
-    ops per call."""
+def concat_device(batches: list[DeviceBatch]) -> DeviceBatch:
+    """Concatenate device batches (same schema) into one batch whose
+    capacity is the bucketed sum of theirs: the live rows of each input, in
+    input order, then zeroed dead rows. ONE fused jitted program per
+    (schema, input shapes), cached module-wide; eager per-column copies
+    would dispatch hundreds of tiny ops per call."""
     assert batches, "concat of zero batches"
-    if len(batches) == 1 and (capacity is None or batches[0].capacity == capacity):
+    if len(batches) == 1:
         return batches[0]
     batches = _colocate(batches)
     schema = batches[0].schema
-    cap = capacity or bucket_capacity(sum(b.capacity for b in batches))
+    # at least the sum of the capacities: _copy_rows relies on it
+    cap = bucket_capacity(sum(b.capacity for b in batches))
     shapes = tuple(tuple(_col_shape_sig(c) for c in b.columns) for b in batches)
-    fn = K.kernel(
-        ("concat", schema, shapes, cap),
-        lambda: K.GuardedJit(lambda bs: _concat_impl(list(bs), cap)),
-    )
+
+    def build():
+        def _concat(bs):  # a device trace names the module after it
+            return _concat_impl(list(bs), cap)
+
+        return K.GuardedJit(_concat)
+
+    # the tag is part of the executable store's key (cache/xla_store.py),
+    # whose fence knows nothing of this file: "concat" is the index-scatter
+    # program of earlier checkouts, which may share the store's directory
+    fn = K.kernel(("concat_copy", schema, shapes, cap), build)
     return fn(tuple(batches))
 
 

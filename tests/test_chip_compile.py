@@ -78,6 +78,38 @@ def test_segscan_compiles_for_v5e_at_full_capacity(one_chip):
     assert time.perf_counter() - t0 < 60
 
 
+def test_concat_compiles_for_v5e_at_q6_shapes(one_chip):
+    """The scan's merge as q6 runs it at SF 1: 8 file batches of 2^20 rows
+    (three doubles and a date, each with its validity) into one of 2^23,
+    every plane a block copy at a traced offset."""
+    import jax
+
+    from spark_rapids_tpu.columnar.device import abstract_batch
+    from spark_rapids_tpu.ops.concat import _concat_impl
+    from spark_rapids_tpu.types import DATE, DOUBLE, Schema, StructField
+
+    schema = Schema(
+        [
+            StructField("l_quantity", DOUBLE),
+            StructField("l_extendedprice", DOUBLE),
+            StructField("l_discount", DOUBLE),
+            StructField("l_shipdate", DATE),
+        ]
+    )
+    batch = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        abstract_batch(schema, 1 << 20),
+    )
+    compiled = (
+        jax.jit(lambda bs: _concat_impl(list(bs), 1 << 23))
+        .lower((batch,) * 8)
+        .compile()
+    )
+    text = compiled.as_text()
+    assert "scatter" not in text
+    assert "dynamic-update-slice" in text
+
+
 def test_ungrouped_sum_compiles_for_v5e(one_chip, monkeypatch):
     """q6's final step — sum(l_extendedprice * l_discount) with no keys —
     at a capacity of 65,536 rows. ops/bits.py asks jax.default_backend()
