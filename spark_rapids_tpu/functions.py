@@ -277,6 +277,12 @@ class Column:
     def asc(self) -> "Column":
         return Column(self.expr)
 
+    def asc_nulls_last(self) -> "Column":
+        """pyspark's marker; TPC-DS orders its rollups NULLS LAST."""
+        c = Column(self.expr)
+        c._sort_nulls_first = False
+        return c
+
     # windowing
     def over(self, window) -> "Column":
         from .expr.windows import WindowExpression, WindowSpec

@@ -388,6 +388,12 @@ CATALOG: Iterable[tuple] = (
     ("kernel.compileDeadlines", MetricKind.COUNTER,
      "first-touch compiles abandoned at spark.rapids.tpu.compile."
      "deadlineSeconds (the op force-opens its circuit breaker)"),
+    # exec/tpu_window.py — counted per kernel launch from values the host
+    # already holds (no device sync)
+    ("window.calls", MetricKind.COUNTER,
+     "window kernel launches (one per merged partition batch)"),
+    ("window.rowsCapacity", MetricKind.COUNTER,
+     "summed row capacity of the merged batches the window kernel was given"),
     # cache/xla_store.py — the persistent XLA executable store
     ("cache.xla.hit", MetricKind.COUNTER,
      "compiled executables deserialized from the on-disk store instead "

@@ -9,8 +9,8 @@ pruned column saves host decode, H2D transfer bytes, and padded-string
 packing work.
 
 Pruning is deliberately conservative: only node types whose column flow is
-fully modeled participate; anything else (joins, expands, windows…) resets
-the requirement to "all columns" beneath it.
+fully modeled participate; anything else resets the requirement to "all
+columns" beneath it.
 """
 from __future__ import annotations
 
@@ -18,6 +18,7 @@ import dataclasses
 from typing import Optional, Set
 
 from ..expr import Expression, UnresolvedAttribute
+from ..expr.base import BoundReference
 from ..types import Schema
 from . import logical as L
 
@@ -27,6 +28,10 @@ def _expr_names(e: Expression, out: Set[str]) -> None:
         out.add(e.name)
     for c in e.children():
         _expr_names(c, out)
+
+
+def _has_bound(e: Expression) -> bool:
+    return isinstance(e, BoundReference) or any(_has_bound(c) for c in e.children())
 
 
 def _names_of(exprs) -> Set[str]:
@@ -98,6 +103,18 @@ def prune_columns(plan: L.LogicalPlan, required: Optional[Set[str]] = None):
             left=prune_columns(plan.left, lreq),
             right=prune_columns(plan.right, rreq),
         )
+    if isinstance(plan, L.Expand) and required is not None:
+        # rollup/cube pass every child column through each projection beside
+        # the nulled keys (session._agg_grouping_sets); the aggregate above
+        # reads the keys and its own inputs. Catalyst prunes the rest
+        # (ColumnPruning on Expand); unpruned, TPC-DS q67 carried all 102
+        # columns of four tables through three joins and nine copies.
+        keep = [i for i, n in enumerate(plan.names) if n in required]
+        projections = [[proj[i] for i in keep] for proj in plan.projections]
+        kept = [e for proj in projections for e in proj]
+        if keep and not any(_has_bound(e) for e in kept):
+            child = prune_columns(plan.child, _names_of(kept))
+            return L.Expand(projections, [plan.names[i] for i in keep], child)
     if isinstance(plan, L.Window):
         # output = child columns ++ window columns: the child must provide
         # the required pass-through names plus every spec/function input.

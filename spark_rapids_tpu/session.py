@@ -1462,7 +1462,10 @@ class DataFrame:
             False if (isinstance(c, Column) and getattr(c, "_sort_desc", False)) else a
             for c, a in zip(cols, ascending)
         ]
-        return [L.SortOrder(e, a) for e, a in zip(exprs, ascending)]
+        return [
+            L.SortOrder(e, a, getattr(c, "_sort_nulls_first", None))
+            for c, e, a in zip(cols, exprs, ascending)
+        ]
 
     def limit(self, n: int) -> "DataFrame":
         return DataFrame(self._session, L.Limit(n, self._plan))

@@ -35,10 +35,10 @@ def _to_orders(cols) -> tuple:
         if isinstance(c, WindowOrder):
             orders.append(c)
             continue
-        if isinstance(c, Column) and getattr(c, "_sort_desc", False):
-            orders.append(WindowOrder(_c2e(c), False, None))
-            continue
-        orders.append(WindowOrder(_c2e(c), True, None))
+        desc = isinstance(c, Column) and getattr(c, "_sort_desc", False)
+        orders.append(
+            WindowOrder(_c2e(c), not desc, getattr(c, "_sort_nulls_first", None))
+        )
     return tuple(orders)
 
 

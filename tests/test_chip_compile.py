@@ -146,3 +146,38 @@ def test_ungrouped_sum_compiles_for_v5e(one_chip, monkeypatch):
         return aggs[0].data, aggs[0].validity, n_groups
 
     jax.jit(q6_sum).lower(batch).compile()
+
+
+def test_window_rank_compiles_for_v5e_at_q67_shapes(one_chip, monkeypatch):
+    """TPC-DS q67's window as the chip runs it at SF 1: rank() over
+    (partition by a string, order by a double descending) on a batch of 2^20
+    rows with five padded string planes. The window kernel had never been
+    lowered for a TPU before PR 27."""
+    import jax
+
+    from spark_rapids_tpu.columnar.device import abstract_batch
+    from spark_rapids_tpu.exec.tpu_window import _make_window_kernel
+    from spark_rapids_tpu.expr.base import BoundReference
+    from spark_rapids_tpu.expr.windows import Rank, WindowExpression, WindowOrder, WindowSpec
+    from spark_rapids_tpu.types import DOUBLE, INT, LONG, STRING, Schema, StructField
+
+    names = ("i_category", "i_class", "i_brand", "i_product_name", "d_year",
+             "d_qoy", "d_moy", "s_store_id", "sumsales")
+    types = (STRING,) * 4 + (LONG,) * 3 + (STRING, DOUBLE)
+    child = Schema([StructField(n, t) for n, t in zip(names, types)])
+    out = Schema(list(child.fields) + [StructField("rk", INT, False)])
+    category, sumsales = BoundReference(0, STRING), BoundReference(8, DOUBLE)
+    spec = WindowSpec((category,), (WindowOrder(sumsales, False),))
+    fn = _make_window_kernel(
+        (category,), ((sumsales, False, False),),
+        (("rk", WindowExpression(Rank(), spec)),), out, child,
+    )
+    assert fn.__name__ == "_window"  # the trace reads jit__window
+    batch = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        abstract_batch(child, 1 << 20, {0: 16, 1: 32, 2: 16, 3: 16, 7: 32}),
+    )
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = jax.jit(fn).lower(batch).compile()
+    # what the program holds while it runs, beside its 0.2 GB of input
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30

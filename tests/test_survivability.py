@@ -137,6 +137,13 @@ def test_watchdog_runs_periodic_evict_stale():
         timeout_s=20.0,
         what="stale peer evicted by the watchdog sweep",
     )
+    # the sweep is process-wide (evict_stale_all) and the scanner outlives the
+    # test: left on, it evicts the executors of whatever file this worker runs
+    # next at an age of 0.15 s (tests/test_multiproc_shuffle.py: KeyError
+    # 'executor-1'). configure() reads the conf at the next admission.
+    s.set_conf("spark.rapids.tpu.watchdog.evictStalePeriod", 0)
+    assert s.range(0, 10).count() == 10
+    assert s.scheduler.watchdog.evict_period_s == 0
 
 
 # ── compile deadlines ──────────────────────────────────────────────────────

@@ -351,3 +351,100 @@ def test_ntile_more_buckets_than_rows():
     assert_cpu_and_tpu_equal(q)
     s = tpu_session({})
     assert sorted(r[2] for r in q(s).collect()) == [1, 2]
+
+
+# ── what a trace and the counters see of the window ────────────────────────
+
+
+def _window_kernels():
+    from spark_rapids_tpu import kernels as K
+
+    return {k: fn for k, fn in K._KERNELS.items() if str(k[0]).startswith("window")}
+
+
+def test_window_kernel_name_and_store_key():
+    """A device trace names the module after the jitted function, and
+    cache/xla_store.py keys a stored executable by the kernel key and fences
+    by jax's version only: under the old tag a store filled by an earlier
+    checkout would hand back the module still named ``jit_fn``."""
+    t = _table(60)
+    s = tpu_session()
+    s.create_dataframe(t).with_column("r", F.rank().over(_w())).collect()
+    mine = _window_kernels()
+    assert mine and {k[0] for k in mine} == {"window_named"}
+    assert {fn._fn.__name__ for fn in mine.values()} == {"_window"}
+
+
+@pytest.mark.parametrize("parts", [1, 3])
+def test_window_counters(parts, monkeypatch):
+    """``window.*`` advance by the launches made, from values the host
+    already holds (plain ints, no device value), and counting costs no
+    ``block_until_ready``: a run with the counters taken out waits exactly
+    as often."""
+    import jax
+
+    from spark_rapids_tpu.exec import tpu_window
+    from spark_rapids_tpu.obs import metrics
+
+    t = _table(200, groups=5)
+    s = tpu_session()
+
+    def query():
+        return (
+            s.create_dataframe(t, num_partitions=parts)
+            .rollup(col("k"), col("s"))
+            .agg(F.sum(col("v")).alias("sv"))
+            .with_column("r", F.rank().over(Window.partition_by("k").order_by(col("sv").desc())))
+        )
+
+    waits = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready", lambda x: waits.append(1) or real(x))
+
+    def counted_run():
+        before, n = dict(metrics.GLOBAL.snapshot()), len(waits)
+        rows = query().collect()
+        after = dict(metrics.GLOBAL.snapshot())
+        return rows, {k: after[k] - before.get(k, 0) for k in after}, len(waits) - n
+
+    query().collect()  # compiled; the runs below launch and no more
+    rows, delta, waits_counting = counted_run()
+    calls = delta["window.calls"]
+    assert calls >= 1
+    # capacities are powers of two that hold every row the windows were given
+    assert delta["window.rowsCapacity"] >= max(len(rows), calls)
+    assert delta["window.rowsCapacity"] % 8 == 0
+    assert len(rows) == len(t.group_by(["k", "s"]).aggregate([]).to_pylist()) + 5 + 1
+
+    class Recorder:
+        seen = []
+
+        def add(self, v):
+            assert type(v) is int, type(v)
+            self.seen.append(v)
+
+    for name in ("_M_CALLS", "_M_ROWS_CAPACITY"):
+        monkeypatch.setattr(tpu_window, name, Recorder())
+    _, delta, waits_plain = counted_run()
+    assert len(Recorder.seen) == 2 * calls
+    assert delta["window.calls"] == 0  # the real one stood still
+    assert waits_plain == waits_counting
+
+
+def test_sort_asc_nulls_last():
+    """``asc_nulls_last`` in sort() and in a window's order: TPC-DS orders its
+    rollups NULLS LAST."""
+    t = _table(120)
+    assert_cpu_and_tpu_equal(
+        lambda s: s.create_dataframe(t, num_partitions=2)
+        .with_column(
+            "r", F.rank().over(Window.partition_by("k").order_by(col("v").asc_nulls_last()))
+        )
+        .sort(col("v").asc_nulls_last(), col("k"), col("ts"), col("s"), col("f")),
+        sort_result=False,
+    )
+    s = tpu_session()
+    got = [r[0] for r in s.create_dataframe(t).sort(col("v").asc_nulls_last()).select("v").collect()]
+    nulls = t.column("v").null_count
+    assert nulls and got[-nulls:] == [None] * nulls and None not in got[:-nulls]
+    assert got[:-nulls] == sorted(got[:-nulls])
