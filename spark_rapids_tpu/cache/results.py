@@ -78,6 +78,11 @@ def key_for(session, final_plan, params=()) -> Tuple[Optional[tuple], tuple]:
     meaningless) — callers treat None as cache-off for this query."""
     from ..plan import reuse
 
+    # a write is an effect, not a result: served from the cache it would
+    # land no file. Its in-memory source is keyed by id(), which a freed
+    # table hands on to the next one, so two writes can share a key
+    if type(final_plan).__name__ == "CpuWriteFilesExec":
+        return None, ()
     try:
         ckey = reuse.canonical_key(final_plan)
     except Exception:

@@ -40,7 +40,7 @@ from ..ops.aggregate import group_aggregate
 from ..ops.concat import concat_device
 from ..ops.gather import bulk_shrink, compact, gather_batch, gather_column
 from ..ops.hash import murmur3_rows, partition_ids
-from ..ops.sortkeys import batch_radix_words, sort_permutation
+from ..ops.sortkeys import packed_key, packed_sort
 from ..plan.logical import SortOrder
 from ..plan.physical import Exec, ExecContext, PartitionSet
 from ..types import Schema, StringType, StructField
@@ -962,8 +962,10 @@ class TpuHashAggregateExec(Exec):
 
             return _width
 
-        key = ("agg_width", grouping, child_schema, pre_filter, has_nans)
-        return K.jit_kernel(key, make)
+        # retagged with the packed sort key: the executable store fences by
+        # jax's version alone (ROADMAP D15) and would serve the older program
+        key = ("agg_width_k32", grouping, child_schema, pre_filter, has_nans)
+        return K.key_sort_kernel(key, make)
 
     def _fused_child(self) -> tuple:
         """(effective child, fused pre_filter) — the filter-fusion decision,
@@ -1197,7 +1199,7 @@ def aggregate_kernel(
         return _aggregate
 
     key = (
-        "agg",
+        "agg_k32",  # retagged with the packed sort key (D15), as "agg_width_k32"
         mode,
         grouping,
         agg_fns,
@@ -1208,7 +1210,7 @@ def aggregate_kernel(
         has_nans,
         collect_width,
     )
-    return K.jit_kernel(key, make)
+    return K.key_sort_kernel(key, make)
 
 
 def aggregate_merge_kernel(
@@ -1238,8 +1240,8 @@ def aggregate_merge_kernel(
 
         return _m
 
-    return K.jit_kernel(
-        ("agg_merge", grouping, agg_fns, out_schema, has_nans), make
+    return K.key_sort_kernel(
+        ("agg_merge_k32", grouping, agg_fns, out_schema, has_nans), make
     )
 
 
@@ -1356,21 +1358,21 @@ def device_sort_fn(order: List[SortOrder]):
         def _sort(batch: DeviceBatch) -> DeviceBatch:
             c = Ctx.for_device(batch)
             live = batch.row_mask()
-            words = []
+            cols = []
             for o in order:
                 col = val_to_column(c, o.child.eval(c), o.child.data_type)
-                col = dc_replace(col, validity=col.validity & live)
-                from ..ops.sortkeys import column_radix_words
-
-                words.extend(
-                    column_radix_words(col, o.ascending, o.resolved_nulls_first())
-                )
-            perm = sort_permutation(words, live)
-            return gather_batch(batch, perm, batch.num_rows)
+                cols.append(dc_replace(col, validity=col.validity & live))
+            key = packed_key(
+                cols,
+                live,
+                [o.ascending for o in order],
+                [o.resolved_nulls_first() for o in order],
+            )
+            return gather_batch(batch, packed_sort(key), batch.num_rows)
 
         return _sort
 
-    return K.jit_kernel(("sort", _order_key(order)), make)
+    return K.key_sort_kernel(("sort_k32", _order_key(order)), make)
 
 
 def device_merge_fn(order: List[SortOrder]):
@@ -1379,7 +1381,7 @@ def device_merge_fn(order: List[SortOrder]):
     gathers through ``merge_permutation``'s binary-search ranks — O(n log n)
     GATHERS per level instead of re-running the sort, whose TPU lowering is
     a sorting network with per-pass cost far above a gather sweep (see
-    sort_permutation's compile-time notes). The reference's true
+    ops/sortkeys.py's compile-time notes). The reference's true
     out-of-core merge (GpuSortExec.scala:212-510). Caveat measured on the
     XLA-CPU backend: its lax.sort is a fast comparison sort, so there the
     re-sort wins — the merge is sized for TPU economics. The concat runs as

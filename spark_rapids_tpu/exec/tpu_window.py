@@ -45,7 +45,7 @@ from ..expr.windows import (
 from ..ops.concat import concat_device
 from ..ops.gather import gather_batch
 from ..ops.scan import segscan as _segscan
-from ..ops.sortkeys import column_radix_words, sort_permutation
+from ..ops.sortkeys import packed_key, packed_sort, segment_starts
 from ..plan.physical import Exec, ExecContext, PartitionSet
 from ..types import Schema, StringType, StructField
 from .tpu import val_to_column
@@ -110,10 +110,11 @@ class TpuWindowExec(Exec):
         out_schema = self._schema
         from .. import kernels as K
 
-        # "window" was the tag while the jitted function was still `fn`; a
-        # store that holds that module must not serve it under the new name
-        key = ("window_named", pkeys, orders, window_cols, out_schema, child_schema)
-        return K.jit_kernel(
+        # a tag per program: "window" while the jitted function was `fn`,
+        # "window_named" with uint64 radix words; a store that holds those
+        # modules must not serve them for this one (ROADMAP D15)
+        key = ("window_k32", pkeys, orders, window_cols, out_schema, child_schema)
+        return K.key_sort_kernel(
             key,
             lambda: _make_window_kernel(
                 pkeys, orders, window_cols, out_schema, child_schema
@@ -131,36 +132,31 @@ def _make_window_kernel(pkeys, orders, window_cols, out_schema, child_schema):
             c = Ctx.for_device(batch)
             live0 = batch.row_mask()
 
-            def words_of(exprs_dirs):
-                words = []
-                for e, asc, nf in exprs_dirs:
-                    col = val_to_column(c, e.eval(c), e.data_type)
-                    col = DeviceColumn(col.dtype, col.data, col.validity & live0, col.lengths)
-                    words.extend(column_radix_words(col, asc, nf))
-                return words
-
-            pk_words = words_of([(p, True, True) for p in pkeys])
-            ok_words = words_of(orders)
-            perm = sort_permutation(pk_words + ok_words, live0)
+            # one packed key: the partition columns, then the order columns
+            dirs = [(p, True, True) for p in pkeys] + list(orders)
+            cols = []
+            for e, _, _ in dirs:
+                col = val_to_column(c, e.eval(c), e.data_type)
+                cols.append(
+                    DeviceColumn(col.dtype, col.data, col.validity & live0, col.lengths)
+                )
+            key = packed_key(
+                cols, live0, [asc for _, asc, _ in dirs], [nf for _, _, nf in dirs]
+            )
+            perm = packed_sort(key)
             sorted_batch = gather_batch(batch, perm, batch.num_rows)
             live = sorted_batch.row_mask()
             idx = jnp.arange(cap, dtype=jnp.int32)
 
-            def starts_from(words):
-                s = idx == 0
-                for w in words:
-                    sw = w[perm]
-                    prev = jnp.concatenate([sw[:1], sw[:-1]])
-                    s = s | (sw != prev)
-                return s & live
-
-            first_live = (idx == 0) & live
-            seg_start = starts_from(pk_words) if pkeys else first_live
-            peer_start = seg_start
-            for w in ok_words:
-                sw = w[perm]
-                prev = jnp.concatenate([sw[:1], sw[:-1]])
-                peer_start = peer_start | ((sw != prev) & live)
+            # the key is read once, sorted; a partition starts where the
+            # partition columns' bits change, a peer group where any do
+            s_words = key.sorted_words(perm)
+            seg_start = (
+                segment_starts(key.prefix(s_words, len(pkeys)), live)
+                if pkeys
+                else (idx == 0) & live
+            )
+            peer_start = segment_starts(s_words, live)
             # padding is its own segment so the last live segment ends at
             # num_rows-1, not cap-1 (lead/default, suffix scans, seg_last)
             pad_start = idx == sorted_batch.num_rows

@@ -46,6 +46,8 @@ _M_WARM_NS = obs_metrics.GLOBAL.timer("kernel.warmTimeNs")
 _M_FIRST_CALLS = obs_metrics.GLOBAL.counter("kernel.firstCalls")
 _M_COMPILE_NS = obs_metrics.GLOBAL.timer("kernel.compileTimeNs")
 _M_COMPILE_HIST = obs_metrics.GLOBAL.histogram("kernel.compileHist")
+_M_KEY_PASSES = obs_metrics.GLOBAL.counter("sort.keyPasses")
+_M_KEY_PASSES_UNPACKED = obs_metrics.GLOBAL.counter("sort.keyPassesUnpacked")
 
 
 def kernel(key: tuple, builder: Callable):
@@ -200,10 +202,13 @@ class GuardedJit:
     lock-free."""
 
     __slots__ = ("_fn", "_seen", "_warmed", "_store_key", "_loaded",
-                 "_unproven", "_digests")
+                 "_unproven", "_digests", "_on_launch")
 
-    def __init__(self, fn, store_key: tuple | None = None):
+    def __init__(self, fn, store_key: tuple | None = None, on_launch=None):
         self._fn = jax.jit(fn)
+        #: called as ``on_launch(sig, args)`` at every call, with the arg
+        #: signature this call computes anyway (host-side launch counters)
+        self._on_launch = on_launch
         self._seen = set()
         self._warmed = set()
         #: persistent identity for the on-disk executable store — the
@@ -322,6 +327,8 @@ class GuardedJit:
             # asserts on
             _faults.on_kernel_stall()
         sig = _args_sig(args)
+        if self._on_launch is not None:
+            self._on_launch(sig, args)
         loaded = self._loaded.get(sig)
         if loaded is not None:
             if sig in self._unproven:
@@ -521,6 +528,44 @@ def guarded_jit(fn) -> GuardedJit:
 def jit_kernel(key: tuple, make_fn: Callable):
     """Shorthand: cache ``GuardedJit(make_fn())`` under ``key``."""
     return kernel(key, lambda: GuardedJit(make_fn()))
+
+
+class _KeyPassCounter:
+    """``on_launch`` hook of a kernel whose program sorts by packed keys (the
+    aggregate, sort and window kernels). Each launch adds the program's sort
+    passes to ``sort.keyPasses`` and what two passes a uint64 radix word
+    would have run to ``sort.keyPassesUnpacked``. Both are static per input
+    signature (key dtypes and string plane widths), so they are read once
+    per signature from an abstract trace — an executable loaded from the
+    store is never traced — and a launch costs a lookup and two counter
+    adds, no device sync."""
+
+    __slots__ = ("_raw", "_passes")
+
+    def __init__(self, raw):
+        self._raw = raw
+        self._passes: dict = {}
+
+    def __call__(self, sig, args) -> None:
+        passes = self._passes.get(sig)
+        if passes is None:
+            from .ops.sortkeys import counting_passes
+
+            with counting_passes() as count:
+                jax.eval_shape(self._raw, *args)
+            passes = self._passes[sig] = tuple(count)
+        _M_KEY_PASSES.add(passes[0])
+        _M_KEY_PASSES_UNPACKED.add(passes[1])
+
+
+def key_sort_kernel(key: tuple, make_fn: Callable):
+    """``jit_kernel`` for a program that sorts by packed keys."""
+
+    def build():
+        raw = make_fn()
+        return GuardedJit(raw, on_launch=_KeyPassCounter(raw))
+
+    return kernel(key, build)
 
 
 def schema_key(schema) -> tuple:

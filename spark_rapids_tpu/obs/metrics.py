@@ -394,6 +394,14 @@ CATALOG: Iterable[tuple] = (
      "window kernel launches (one per merged partition batch)"),
     ("window.rowsCapacity", MetricKind.COUNTER,
      "summed row capacity of the merged batches the window kernel was given"),
+    # kernels.py key_sort_kernel — per launch of an aggregate, sort or window
+    # kernel, static per kernel and input signature (no device sync)
+    ("sort.keyPasses", MetricKind.COUNTER,
+     "sort passes run over packed key words (ops/sortkeys.py packed_sort: "
+     "one stable single-key uint32 pass a word)"),
+    ("sort.keyPassesUnpacked", MetricKind.COUNTER,
+     "passes the same sorts would have run at two a uint64 radix word; "
+     "over sort.keyPasses it is how far the packing engages"),
     # cache/xla_store.py — the persistent XLA executable store
     ("cache.xla.hit", MetricKind.COUNTER,
      "compiled executables deserialized from the on-disk store instead "

@@ -29,7 +29,12 @@ from ..columnar.device import DeviceBatch, DeviceColumn
 from ..types import LONG, DoubleType, FloatType, StringType
 from .gather import gather_column
 from .scan import first_k_positions, seg_end_flags, segscan
-from .sortkeys import batch_radix_words, segment_starts, sort_permutation
+from .sortkeys import (
+    column_radix_words,
+    packed_key,
+    packed_sort,
+    segment_starts,
+)
 
 _BIG = jnp.int32(2**31 - 1)
 
@@ -98,8 +103,6 @@ def _had_nan_scan(data, valid, starts):
 def _string_base_words(col: DeviceColumn):
     """Ascending sortable uint64 value words of a string column (computed
     once per column even when both min AND max aggregate it)."""
-    from .sortkeys import column_radix_words
-
     return column_radix_words(
         col, ascending=True, nulls_first=True, value_only=True
     )
@@ -186,16 +189,17 @@ def group_aggregate(
             collect_width=collect_width, has_nans=has_nans,
         )
 
-    words = batch_radix_words(keys)
     row_mask = batch.row_mask() if live_mask is None else live_mask
     n_live = (
         batch.num_rows if live_mask is None else live_mask.sum().astype(jnp.int32)
     )
-    perm = sort_permutation(words, row_mask)
+    # a row's key is read once, as its packed words; every full-capacity
+    # gather below is ~3x the sort pass it would feed (PERF.md section 5)
+    key = packed_key(keys, row_mask)
+    perm = packed_sort(key)
     # live rows sort first, so the sorted live mask is a prefix of n_live
     live = jnp.arange(cap, dtype=jnp.int32) < n_live
-    s_words = [w[perm] for w in words]
-    starts = segment_starts(s_words, live)
+    starts = segment_starts(key.sorted_words(perm), live)
     num_groups = jnp.maximum(starts.sum().astype(jnp.int32), min_groups)
     group_live = jnp.arange(cap, dtype=jnp.int32) < num_groups
     idx = jnp.arange(cap, dtype=jnp.int32)
@@ -208,11 +212,12 @@ def group_aggregate(
     start_pos = first_k_positions(starts)
     end_pos = first_k_positions(ends)
 
-    # representative keys: the first sorted row of each segment
+    # representative keys: the first sorted row of each segment, gathered
+    # once from the unsorted columns (not at perm and again at start_pos)
+    first_row = perm[start_pos]
     out_keys: list[DeviceColumn] = []
     for k in keys:
-        sk = gather_column(k, perm)
-        gk = gather_column(sk, start_pos, group_live)
+        gk = gather_column(k, first_row, group_live)
         out_keys.append(
             DeviceColumn(
                 k.dtype,
@@ -234,7 +239,7 @@ def group_aggregate(
                     op,
                     col,
                     sc,
-                    words,
+                    keys,
                     row_mask,
                     n_live,
                     live,
@@ -326,11 +331,10 @@ def group_max_size(batch: DeviceBatch, key_ordinals: list[int], live_mask=None,
     )
     if not keys:
         return n_live.astype(jnp.int32)
-    words = batch_radix_words(keys)
-    perm = sort_permutation(words, row_mask)
+    key = packed_key(keys, row_mask)
+    perm = packed_sort(key)
     live = jnp.arange(cap, dtype=jnp.int32) < n_live
-    s_words = [w[perm] for w in words]
-    starts = segment_starts(s_words, live)
+    starts = segment_starts(key.sorted_words(perm), live)
     run = segscan(jnp.ones(cap, jnp.int32), starts, jnp.add)
     return jnp.where(live, run, 0).max().astype(jnp.int32)
 
@@ -339,7 +343,7 @@ def _group_collect(
     op: str,
     col: DeviceColumn,
     sc: DeviceColumn,
-    key_words: list,
+    keys: list,
     row_mask,
     n_live,
     live,
@@ -363,24 +367,21 @@ def _group_collect(
     value-ascending — deterministic, and mirrored by the CPU engine (Spark
     itself guarantees no order)."""
     from ..types import ArrayType
-    from .sortkeys import column_radix_words
 
     idx = jnp.arange(cap, dtype=jnp.int32)
     if op == "collect_set":
         vcol = _normalize_float(col, has_nans)
-        vwords = column_radix_words(vcol, ascending=True, nulls_first=False)
-        words2 = key_words + vwords
-        perm2 = sort_permutation(words2, row_mask)
-        s_keywords = [w[perm2] for w in key_words]
-        starts2 = segment_starts(s_keywords, live)
+        # one key: the group's columns, then the value (nulls last)
+        key2 = packed_key(
+            keys + [vcol], row_mask, nulls_firsts=[True] * len(keys) + [False]
+        )
+        perm2 = packed_sort(key2)
+        s_words2 = key2.sorted_words(perm2)
+        starts2 = segment_starts(key2.prefix(s_words2, len(keys)), live)
         sc2 = gather_column(vcol, perm2)
         v2 = sc2.validity & live
-        diff = jnp.zeros(cap, dtype=bool)
-        for w in vwords:
-            sw = w[perm2]
-            prev = jnp.concatenate([sw[:1], sw[:-1]])
-            diff = diff | (sw != prev)
-        keep = v2 & (starts2 | diff)
+        # a kept row differs from the one before it in group or in value
+        keep = v2 & segment_starts(s_words2, live)
         ends2 = seg_end_flags(starts2 | (idx == n_live)) & live
         end_pos2 = first_k_positions(ends2)
         use_sc, use_starts, use_end_pos = sc2, starts2, end_pos2
@@ -465,23 +466,16 @@ def _ungrouped_aggregate(
             )
         elif op in ("collect_list", "collect_set"):
             from ..types import ArrayType
-            from .sortkeys import column_radix_words
 
             W = max(collect_width, 1)
             if op == "collect_set":
                 vcol = _normalize_float(col, has_nans)
-                vwords = column_radix_words(
-                    vcol, ascending=True, nulls_first=False
-                )
-                perm2 = sort_permutation(vwords, valid)
+                key2 = packed_key([vcol], valid, nulls_firsts=[False])
+                perm2 = packed_sort(key2)
                 svals = gather_column(vcol, perm2)
                 v2 = valid[perm2]
-                diff = jnp.zeros(cap, dtype=bool)
-                for w in vwords:
-                    sw = w[perm2]
-                    prev = jnp.concatenate([sw[:1], sw[:-1]])
-                    diff = diff | (sw != prev)
-                keep = v2 & ((idx == 0) | diff)
+                # valid rows sort first: among them a new value starts a run
+                keep = segment_starts(key2.sorted_words(perm2), v2)
             else:
                 from .gather import compact_permutation
 

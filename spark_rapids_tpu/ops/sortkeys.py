@@ -2,11 +2,21 @@
 sort-based group-by, and sort-merge machinery.
 
 The reference leans on cudf's type-aware comparators (Table.orderBy,
-groupBy). The TPU-first design instead maps every SQL value to one or more
-**uint64 radix words whose unsigned order equals Spark's sort order**, then
-uses a single variadic ``jax.lax.sort`` over all words (XLA sorts
-lexicographically by the first ``num_keys`` operands) — one fused kernel, no
-custom comparators, static shapes.
+groupBy). The TPU-first design instead maps every SQL value to bits whose
+unsigned order equals Spark's sort order and sorts them with an LSD radix
+sort of stable single-key ``jax.lax.sort`` passes — one fused kernel, no
+custom comparators, static shapes. Two encodings of the same order:
+
+* **fields** (``column_key_fields`` → ``packed_key``): every value as uint32
+  fields of static bit widths (a validity bit, an int8 in 8 bits, a string's
+  length in ``plane_width.bit_length()``), concatenated into the fewest
+  uint32 words. What the sort, the group-by and the window run: a sort pass
+  and two full-capacity gathers cost per WORD, so the key carries no bit
+  that is known to be zero.
+* **uint64 radix words** (``column_radix_words``): one or more whole words a
+  column, comparable across columns of different integer widths. What the
+  join, the out-of-core merge, the range bounds and the string min/max
+  arg-scan compare.
 
 Orderings implemented to Spark's spec:
 * NULLs first/last via a leading validity word
@@ -17,6 +27,10 @@ Orderings implemented to Spark's spec:
 * descending via bitwise complement of the value words
 """
 from __future__ import annotations
+
+import contextlib
+import threading
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -33,15 +47,19 @@ from ..types import (
 _SIGN64 = jnp.uint64(1 << 63)
 
 
+def _float32_bits_ordered(data: jax.Array) -> jax.Array:
+    """Map float32 to uint32 preserving Spark order (NaN greatest, -0==0)."""
+    x = data.astype(jnp.float32)
+    x = jnp.where(x == 0.0, jnp.float32(0.0), x)  # -0.0 -> +0.0
+    x = jnp.where(jnp.isnan(x), jnp.float32(jnp.nan), x)  # canonical NaN
+    u = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(u >> jnp.uint32(31) == jnp.uint32(1), ~u, u | jnp.uint32(1 << 31))
+
+
 def _float_bits_ordered(data: jax.Array, dt: DataType) -> jax.Array:
     """Map float to uint64 preserving Spark order (NaN greatest, -0==0)."""
     if isinstance(dt, FloatType):
-        x = data.astype(jnp.float32)
-        x = jnp.where(x == 0.0, jnp.float32(0.0), x)  # -0.0 -> +0.0
-        x = jnp.where(jnp.isnan(x), jnp.float32(jnp.nan), x)  # canonical NaN
-        b = jax.lax.bitcast_convert_type(x, jnp.int32).astype(jnp.int64)
-        flipped = jnp.where(b < 0, ~b, b | jnp.int64(1 << 31))
-        return flipped.astype(jnp.uint64)
+        return _float32_bits_ordered(data).astype(jnp.uint64)
     from .bits import f64_bits
 
     x = data.astype(jnp.float64)
@@ -129,38 +147,16 @@ def column_radix_words(
     return [vw] + words
 
 
-def batch_radix_words(
-    columns: list[DeviceColumn],
-    ascendings: list[bool] | None = None,
-    nulls_firsts: list[bool] | None = None,
-) -> list[jax.Array]:
-    out: list[jax.Array] = []
-    for i, c in enumerate(columns):
-        asc = True if ascendings is None else ascendings[i]
-        nf = True if nulls_firsts is None else nulls_firsts[i]
-        out.extend(column_radix_words(c, asc, nf))
-    return out
-
-
 def sort_permutation(
     words: list[jax.Array],
     row_mask: jax.Array,
     live_first: bool = True,
 ) -> jax.Array:
-    """Stable sort permutation over radix words; padding rows sort last.
-
-    Implemented as an LSD radix sort: a ``lax.scan`` of stable SINGLE-key
-    ``lax.sort`` passes from the least- to the most-significant word. XLA's
-    TPU sort lowering compiles a full sorting network whose compile time
-    grows sharply with both array size and operand count — a variadic
-    ``lax.sort`` over k words compiled in O(minutes) at 2^16+ rows, while
-    this form embeds exactly ONE two-operand sort in the program regardless
-    of key count (the scan reuses it per word), with identical ordering
-    semantics (stable passes ⇒ lexicographic). Each 64-bit word goes as two
-    32-bit passes: 64-bit types are emulated on the TPU, and the one
-    embedded sort compiles about three times faster on a uint32 key.
-    """
-    cap = words[0].shape[0]
+    """Stable sort permutation over uint64 radix words; padding rows sort
+    last. Each 64-bit word goes as two 32-bit passes of ``_radix_passes``:
+    64-bit types are emulated on the TPU, and the one embedded sort compiles
+    about three times faster on a uint32 key. Callers whose key is theirs
+    alone (sort, group-by, window) run ``packed_key`` instead."""
     halves = []  # most-significant first
     if live_first:
         halves.append(jnp.where(row_mask, jnp.uint32(0), jnp.uint32(1)))
@@ -168,10 +164,27 @@ def sort_permutation(
         w = w.astype(jnp.uint64)
         halves.append((w >> jnp.uint64(32)).astype(jnp.uint32))
         halves.append(w.astype(jnp.uint32))
-    stacked = jnp.stack(halves[::-1])  # least-significant half first
+    return _radix_passes(jnp.stack(halves))
+
+
+def _radix_passes(keys: jax.Array) -> jax.Array:
+    """Stable sort permutation by ``keys``, uint32[nkeys, cap] with the most
+    significant key first.
+
+    An LSD radix sort: a ``lax.scan`` of stable SINGLE-key ``lax.sort``
+    passes from the least- to the most-significant key. XLA's TPU sort
+    lowering compiles a full sorting network whose compile time grows
+    sharply with both array size and operand count — a variadic
+    ``lax.sort`` over k words compiled in O(minutes) at 2^16+ rows, while
+    this form embeds exactly ONE two-operand sort in the program regardless
+    of key count (the scan reuses it per key), with identical ordering
+    semantics (stable passes ⇒ lexicographic). A pass is the gather
+    ``w[perm]`` and the sort; on a v5e the gather is the larger part
+    (PERF.md section 5), so what a sort costs is its number of keys."""
+    cap = keys.shape[1]
     # inherit the data's varying-axis type so the scan carry matches inside
     # shard_map (a plain iota is replicated; the sorted perm is varying)
-    iota = jnp.arange(cap, dtype=jnp.int32) + (stacked[0] * jnp.uint32(0)).astype(
+    iota = jnp.arange(cap, dtype=jnp.int32) + (keys[0] * jnp.uint32(0)).astype(
         jnp.int32
     )
 
@@ -179,8 +192,165 @@ def sort_permutation(
         _, perm = jax.lax.sort((w[perm], perm), num_keys=1, is_stable=True)
         return perm, None
 
-    perm, _ = jax.lax.scan(one_pass, iota, stacked)
+    # least significant key first: the same array, read from its end
+    perm, _ = jax.lax.scan(one_pass, iota, keys, reverse=True)
     return perm
+
+
+# ── the key as one packed bit string ─────────────────────────────────────
+def column_key_fields(
+    col: DeviceColumn, ascending: bool = True, nulls_first: bool = True
+) -> list[tuple[jax.Array, int]]:
+    """Encode one column as ``(uint32 array, bits)`` fields, most significant
+    first: every array holds values below ``2**bits``, and the unsigned
+    order of the concatenated bits is the requested Spark ordering — the
+    same order, ties included, as ``column_radix_words`` gives.
+
+    A validity bit leads (nulls first/last); bool is 1 bit; int8/16/32, date
+    and float32 their 8/16/32-bit order-preserving code; 64-bit types two
+    32-bit fields; a string its plane bytes big-endian, 4 a field, then its
+    length in ``plane_width.bit_length()`` bits (ties by length stay exact,
+    interior NULs included). Descending complements the value bits within
+    their width, never the validity bit; null slots hold zero value bits so
+    that equal keys are equal bits. Built in uint32 throughout: 64-bit
+    integers are emulated on the TPU."""
+    dt, valid = col.dtype, col.validity
+    null_bit = jnp.uint32(0 if nulls_first else 1)
+    fields = [(jnp.where(valid, jnp.uint32(1) - null_bit, null_bit), 1)]
+
+    def value(enc: jax.Array, bits: int):
+        if not ascending:
+            enc = enc ^ jnp.uint32((1 << bits) - 1)
+        return (jnp.where(valid, enc, jnp.uint32(0)), bits)
+
+    if isinstance(dt, StringType):
+        data = col.data
+        cap, w = data.shape
+        for k in range(0, w, 4):
+            n = min(4, w - k)
+            enc = data[:, k].astype(jnp.uint32)
+            for j in range(1, n):
+                enc = (enc << jnp.uint32(8)) | data[:, k + j].astype(jnp.uint32)
+            fields.append(value(enc, 8 * n))
+        fields.append(value(col.lengths.astype(jnp.uint32), int(w).bit_length()))
+    elif isinstance(dt, BooleanType):
+        fields.append(value(col.data.astype(jnp.bool_).astype(jnp.uint32), 1))
+    elif isinstance(dt, FloatType):
+        fields.append(value(_float32_bits_ordered(col.data), 32))
+    elif isinstance(dt, DoubleType):
+        u = _float_bits_ordered(col.data, dt)  # 64-bit by nature of the data
+        hi = (u >> jnp.uint64(32)).astype(jnp.uint32)
+        fields += [value(hi, 32), value(u.astype(jnp.uint32), 32)]
+    elif dt.np_dtype.itemsize <= 4:  # int8/16/32, date (and NullType's plane)
+        bits = 8 * dt.np_dtype.itemsize
+        enc = jax.lax.bitcast_convert_type(col.data.astype(jnp.int32), jnp.uint32)
+        enc = (enc + jnp.uint32(1 << (bits - 1))) & jnp.uint32((1 << bits) - 1)
+        fields.append(value(enc, bits))
+    else:  # int64 / timestamp / decimal(int64)
+        x = col.data.astype(jnp.int64)
+        hi = (x >> jnp.int64(32)).astype(jnp.uint32) ^ jnp.uint32(1 << 31)
+        fields += [value(hi, 32), value(x.astype(jnp.uint32), 32)]
+    return fields
+
+
+def unpacked_key_words(col: DeviceColumn) -> int:
+    """How many uint64 words ``column_radix_words`` makes of this column:
+    two sort passes each. Static: the dtype and the plane width."""
+    if isinstance(col.dtype, StringType):
+        return 1 + (col.data.shape[1] + 7) // 8 + 1
+    return 1 if col.dtype.np_dtype.itemsize <= 4 else 2
+
+
+def pack_fields(fields: list[tuple[jax.Array, int]]) -> list[jax.Array]:
+    """Concatenate fields, most significant first, into the fewest uint32
+    words; a field is split across a word boundary where it falls, and the
+    last word's spare low bits are zero."""
+    nwords = -(-sum(bits for _, bits in fields) // 32)
+    words: list = [None] * nwords
+
+    def put(i, part):
+        words[i] = part if words[i] is None else words[i] | part
+
+    pos = 0
+    for arr, bits in fields:
+        i, end = pos // 32, pos % 32 + bits
+        if end <= 32:
+            put(i, arr << jnp.uint32(32 - end) if end < 32 else arr)
+        else:  # the low ``end - 32`` bits open the next word
+            put(i, arr >> jnp.uint32(end - 32))
+            put(i + 1, arr << jnp.uint32(64 - end))
+        pos += bits
+    return words
+
+
+class PackedKey(NamedTuple):
+    """A sort/group key as one bit string in uint32 words."""
+
+    words: jax.Array  # uint32[nwords, cap], most significant word first
+    column_end_bits: tuple  # where each column's fields end in the string
+    unpacked_passes: int  # what two passes a uint64 radix word would run
+
+    def sorted_words(self, perm: jax.Array) -> jax.Array:
+        """Every word through ``perm`` in ONE gather. On a v5e a gather costs
+        by the index, not by the byte: eight words of the stack move in
+        1.7 times what one alone does, where a gather a word is 8 times
+        (PERF.md section 6, PR 28)."""
+        return self.words[:, perm]
+
+    def prefix(self, words: jax.Array, ncols: int) -> jax.Array:
+        """Of ``words`` (these, or these gathered), what holds the first
+        ``ncols`` columns: whole words, and the last one masked."""
+        full, rem = divmod(self.column_end_bits[ncols - 1], 32)
+        if not rem:
+            return words[:full]
+        last = words[full] & jnp.uint32(((1 << rem) - 1) << (32 - rem))
+        return jnp.concatenate([words[:full], last[None]])
+
+
+def packed_key(
+    columns: list[DeviceColumn],
+    row_mask: jax.Array,
+    ascendings: list[bool] | None = None,
+    nulls_firsts: list[bool] | None = None,
+) -> PackedKey:
+    """The key over ``columns`` with a leading live flag (padding rows sort
+    last): the order of ``sort_permutation`` over every column's
+    ``column_radix_words``, in as few words as its bits need."""
+    fields = [(jnp.where(row_mask, jnp.uint32(0), jnp.uint32(1)), 1)]
+    ends, unpacked = [], 1
+    for i, c in enumerate(columns):
+        asc = True if ascendings is None else ascendings[i]
+        nf = True if nulls_firsts is None else nulls_firsts[i]
+        fields.extend(column_key_fields(c, asc, nf))
+        ends.append(sum(bits for _, bits in fields))
+        unpacked += 2 * unpacked_key_words(c)
+    return PackedKey(jnp.stack(pack_fields(fields)), tuple(ends), unpacked)
+
+
+_PASSES = threading.local()
+
+
+@contextlib.contextmanager
+def counting_passes():
+    """Collect ``[passes run, passes unpacked]`` of every ``packed_sort``
+    traced on this thread inside the block (``exec/``'s launch counters
+    ``sort.keyPasses`` and ``sort.keyPassesUnpacked`` read it once per
+    kernel and input signature, under ``jax.eval_shape``)."""
+    _PASSES.count = count = [0, 0]
+    try:
+        yield count
+    finally:
+        _PASSES.count = None
+
+
+def packed_sort(key: PackedKey) -> jax.Array:
+    """Stable sort permutation by a packed key: one pass a word. By
+    stability the permutation is that of the unpacked form, bit for bit."""
+    count = getattr(_PASSES, "count", None)
+    if count is not None:
+        count[0] += key.words.shape[0]
+        count[1] += key.unpacked_passes
+    return _radix_passes(key.words)
 
 
 def _lex_less(words_a: list[jax.Array], words_b: list[jax.Array], or_equal: bool):
@@ -285,13 +455,10 @@ def np_column_radix_words(
     return [vw] + words
 
 
-def segment_starts(words: list[jax.Array], row_mask: jax.Array) -> jax.Array:
-    """bool[cap]: row i starts a new group (equal radix words ⇔ equal keys).
-    Assumes rows already sorted by ``words`` with live rows first."""
-    cap = words[0].shape[0]
-    diff = jnp.zeros(cap, dtype=bool)
-    for w in words:
-        prev = jnp.concatenate([w[:1], w[:-1]])
-        diff = diff | (w != prev)
-    first = jnp.arange(cap) == 0
-    return (diff | first) & row_mask
+def segment_starts(words, row_mask: jax.Array) -> jax.Array:
+    """bool[cap]: row i starts a new group (equal words ⇔ equal keys).
+    ``words`` is ``[nwords, cap]`` (or a list of ``[cap]`` words), the rows
+    already sorted by them with live rows first."""
+    w = words if isinstance(words, jax.Array) else jnp.stack(words)
+    diff = (w[:, 1:] != w[:, :-1]).any(axis=0)
+    return jnp.concatenate([jnp.ones(1, dtype=bool), diff]) & row_mask

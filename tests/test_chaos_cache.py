@@ -130,6 +130,22 @@ def test_writer_append_invalidates_cached_file_scan(tmp_path):
     )
 
 
+def test_write_is_never_served_from_result_cache(tmp_path):
+    """A write plan over an in-memory table is keyed by the table's id(),
+    which a freed table hands on to the next: served from the cache, the
+    second write landed no file (the cause of the stale read the test
+    above saw once in a full run). Writing ONE table twice makes the keys
+    collide every time."""
+    session = tpu_session(
+        {"spark.rapids.tpu.resultCache.enabled": True}, strict=False
+    )
+    path = str(tmp_path / "t")
+    df = session.create_dataframe(_table(1, 64))
+    df.write.mode("overwrite").parquet(path)
+    df.write.mode("append").parquet(path)
+    assert session.read.parquet(path).count() == 128
+
+
 # ── owner killed mid-materialization ───────────────────────────────────────
 
 
