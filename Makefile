@@ -1,6 +1,6 @@
 # Developer entry points. The test environment pins jax to the CPU backend
-# with 8 virtual devices (tests/conftest.py); bench/driver runs use the real
-# TPU chip.
+# with 8 virtual devices (tests/conftest.py); the benchmark (BENCHMARK.json,
+# benchmark/run.py) runs on the real TPU chip.
 
 PY ?= python
 PYTEST = $(PY) -m pytest
@@ -106,22 +106,6 @@ docs:
 golden:
 	$(PY) tests/golden/gen_golden.py
 
-# Local CPU-backend dry run of the benchmark rig at a small scale factor.
-.PHONY: bench-dry
-bench-dry:
-	BENCH_PLATFORM=cpu BENCH_SF=0.02 BENCH_PARTITIONS=2 \
-	  BENCH_SHUFFLE_PARTITIONS=2 BENCH_RUNS=1 $(PY) bench.py
-
-# The recorded BENCH_r06 invocation: full TPC-H on the real TPU backend
-# with whole-stage fusion + shape bucketing (default-on) and calibrated
-# engine routing enabled. BENCH_ASSERT_BACKEND makes the rig exit 2 if the
-# process initialized anything but a TPU — a CPU smoke run must never ship
-# under the r06 label. The result JSON lands in BENCH_r06.json.
-.PHONY: bench-r06
-bench-r06:
-	BENCH_ASSERT_BACKEND=tpu BENCH_OUT=BENCH_r06.json BENCH_ROUTING=1 \
-	  $(PY) bench.py
-
 # Start the Arrow-IPC SQL endpoint with the TPC-H demo catalog registered
 # as temp views (docs/serving.md). Connect with:
 #   python -c "from spark_rapids_tpu.serve import connect; \
@@ -131,40 +115,6 @@ SERVE_SF ?= 0.01
 .PHONY: serve
 serve:
 	$(PY) -m spark_rapids_tpu.serve --port $(SERVE_PORT) --tpch-sf $(SERVE_SF)
-
-# Closed-loop serving SLO benchmark (N clients x target qps over the wire;
-# emits SLO_r07.json with p50/p95/p99 wait+run latency, per-tenant qps, and
-# the overload block: OVERLOADED rejections + retry-after + admitted-p99 vs
-# uncontended-p99 ratio. Drive past sustainable qps with BENCH_SERVE_QPS;
-# bound capacity with BENCH_SERVE_PERMITS / BENCH_SERVE_MAXQUEUED and set
-# per-query deadlines with BENCH_SERVE_DEADLINE — clients are closed-loop,
-# so overload needs clients > permits + maxQueued).
-.PHONY: bench-serve
-bench-serve:
-	BENCH_PLATFORM=$(or $(BENCH_PLATFORM),cpu) BENCH_SF=0.05 \
-	  BENCH_RUNS=1 $(PY) bench.py --serve 4
-
-# The recorded overload scenario behind SLO_r07.json: 6 closed-loop clients
-# at 2x the single-permit sustainable rate, queue bounded at 8, per-query
-# deadline ~1.5x the uncontended p99 — admitted-query p99 must stay within
-# 1.5x uncontended while rejections carry retry-after hints.
-.PHONY: bench-serve-overload
-bench-serve-overload:
-	BENCH_PLATFORM=cpu BENCH_SF=0.02 BENCH_RUNS=1 \
-	  BENCH_SERVE_QPS=4 BENCH_SERVE_SECONDS=12 BENCH_SERVE_DEADLINE=1.3 \
-	  BENCH_SERVE_PERMITS=1 BENCH_SERVE_MAXQUEUED=8 \
-	  $(PY) bench.py --serve 6 --smoke
-
-# Live-analytics SLO (ISSUE 20): paced appends against an incrementally
-# maintained aggregate on a small table vs a 10x larger one (equal delta
-# size) plus a full-refresh control, N wire subscribers draining UPDATE
-# trains — refresh-latency percentiles must scale with the DELTA, not the
-# table (SLO_r09.json: delta_scaling_p50_ratio ~1, incremental speedup
-# vs the full-refresh control).
-.PHONY: bench-live
-bench-live:
-	BENCH_PLATFORM=$(or $(BENCH_PLATFORM),cpu) BENCH_SF=0.01 \
-	  BENCH_RUNS=1 $(PY) bench.py --live 4
 
 # Live-analytics chaos suite (ISSUE 20): appender storms against wire
 # subscriber fleets with per-epoch bit-identity oracles replayed from the
@@ -211,14 +161,3 @@ chaos-recovery:
 .PHONY: chaos
 chaos:
 	$(PYTEST) -q -m chaos
-
-# Trace one TPC-H query through the bench rig: `make trace Q=6` writes
-# traces/query-<n>.trace.json (open at ui.perfetto.dev), the per-query
-# metrics artifact, and a Prometheus dump (docs/observability.md).
-TRACE_DIR ?= traces
-Q ?= 6
-.PHONY: trace
-trace:
-	BENCH_PLATFORM=$(or $(BENCH_PLATFORM),cpu) BENCH_SF=0.05 \
-	  BENCH_PARTITIONS=2 BENCH_SHUFFLE_PARTITIONS=2 BENCH_RUNS=1 \
-	  $(PY) bench.py --trace-dir $(TRACE_DIR) --queries $(Q)

@@ -198,8 +198,8 @@ class SourceFile:
 
 
 class Project:
-    """The analysis unit: every engine source file plus bench.py, parsed
-    once and shared by all passes."""
+    """The analysis unit: every source file of the package, parsed once
+    and shared by all passes."""
 
     def __init__(self, root: str, files: Sequence[SourceFile]):
         self.root = root
@@ -219,8 +219,6 @@ class Project:
                     rels.append(
                         os.path.relpath(os.path.join(base, name), root)
                     )
-        if os.path.exists(os.path.join(root, "bench.py")):
-            rels.append("bench.py")
         return cls(root, [SourceFile(root, r) for r in sorted(rels)])
 
     def file(self, rel: str) -> Optional[SourceFile]:

@@ -41,14 +41,13 @@ _DYNAMIC_NAMESPACES = (
 )
 
 #: files allowed to read startup_only entries: the session-construction
-#: surface, the registry itself, docs generation, and the bench/server
+#: surface, the registry itself, docs generation, and the server
 #: bootstrap (all run before or at session init)
 ALLOWED_STARTUP_READERS = (
     "spark_rapids_tpu/session.py",
     "spark_rapids_tpu/config.py",
     "spark_rapids_tpu/docs_gen.py",
     "spark_rapids_tpu/serve/__main__.py",
-    "bench.py",
 )
 
 

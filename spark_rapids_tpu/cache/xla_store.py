@@ -12,18 +12,36 @@ Robustness is the headline, not the cache. A store that can be corrupted,
 version-skewed, or half-written must degrade to a fresh compile — never to
 a crash, and never to a wrong answer:
 
-- **Entry identity** is a SHA-256 over a *stable structural fingerprint*
-  of the kernel's cache key (the same structural identity discipline as
+- **Entry identity** is a SHA-256 over the engine's own source
+  (``source_digest``) and a *stable structural fingerprint* of the
+  kernel's cache key (the same structural identity discipline as
   ``plan/reuse.py::canonical_key``: frozen expression trees, schema
   signatures, batch geometry from the jit arg signature). Anything whose
   identity cannot be proven stable across processes (an ``id()``-bearing
-  repr, an elided ndarray repr) makes the kernel non-persistable — a
-  false MISS is duplicate work; a false HIT would be a wrong executable.
-- **Version fencing**: the entry header records format version, engine
-  schema revision, jax/jaxlib versions, backend platform and platform
-  fingerprint. ANY mismatch is a silent miss — the payload is never even
-  deserialized (deserialization is pickle; feeding it bytes written by a
-  different software version is how caches turn into crash loops).
+  repr, an elided ndarray repr, a package whose source cannot be read)
+  makes the kernel non-persistable — a false MISS is duplicate work; a
+  false HIT would be a wrong executable.
+- **Staleness is decided here and nowhere else.** An executable is served
+  only to the source that compiled it: ``source_digest`` is part of the
+  entry's NAME, so a kernel whose body changes under an unchanged cache
+  key misses, and no call site carries a revision in its key tag (tags
+  name kernels). In the name and not the header, because a mismatching
+  header is a miss followed by an overwrite: two checkouts sharing one
+  directory (a benchmark's parent and change, a fleet mid-upgrade) would
+  evict each other's entries at every boot. Under distinct names their
+  entries lie side by side and the older ones age out through the LRU.
+  The whole package and not finer: a digest of each kernel's lowered
+  text would be exact but needs the trace and the lowering this store
+  exists to skip, and a per-module reachability digest is a second thing
+  to keep right. The price is that any change to the package re-lowers
+  every kernel once per checkout; jax's own cache (keyed by the HLO, so
+  never stale) still serves the compile of every unchanged program that
+  took long enough to be kept there.
+- **Version fencing**: the entry header records format version,
+  jax/jaxlib versions, backend platform and platform fingerprint. ANY
+  mismatch is a silent miss — the payload is never even deserialized
+  (deserialization is pickle; feeding it bytes written by a different
+  software version is how caches turn into crash loops).
 - **Atomic writes**: temp file in ``tmp/`` + fsync + ``os.replace``; a
   crash between temp and rename leaves an orphan that no load ever sees
   and a later boot sweeps (dead-pid detection).
@@ -51,6 +69,7 @@ nothing here may fail a query.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import json
 import logging
@@ -69,13 +88,6 @@ log = logging.getLogger(__name__)
 
 #: on-disk container format revision — bump on any layout change
 FORMAT_VERSION = 2
-#: engine kernel-semantics revision — bump whenever a kernel's compiled
-#: behavior changes without its cache key changing (an executable compiled
-#: by the old engine would silently compute the OLD semantics)
-#: rev 2: Schema fingerprints include field nullability (a Schema repr
-#: hides it, so two kernels differing only in nullable flags collided on
-#: one digest and quarantine-thrashed each other at every proving run)
-SCHEMA_REV = 2
 MAGIC = b"SRTXC01\n"
 _ENTRY_EXT = ".xc"
 
@@ -116,7 +128,6 @@ def fence() -> dict:
             )
         _FENCE = {
             "format": FORMAT_VERSION,
-            "schema_rev": SCHEMA_REV,
             "jax": jax.__version__,
             "jaxlib": jaxlib.__version__,
             "backend": backend,
@@ -127,6 +138,49 @@ def fence() -> dict:
             "device_count": n_devices,
         }
     return _FENCE
+
+
+# ── the engine's source ─────────────────────────────────────────────────────
+
+def _digest_tree(root: str) -> Optional[str]:
+    """SHA-256 over the sorted relative paths and the bytes of every ``.py``
+    file under ``root`` (``__pycache__`` skipped), or None when the tree
+    cannot be read or holds no source (an import from an archive)."""
+    paths = []
+    for dirpath, dirnames, filenames in os.walk(root):
+        dirnames[:] = [d for d in dirnames if d != "__pycache__"]
+        for name in filenames:
+            if name.endswith(".py"):
+                full = os.path.join(dirpath, name)
+                rel = os.path.relpath(full, root).replace(os.sep, "/")
+                paths.append((rel, full))
+    if not paths:
+        return None
+    h = hashlib.sha256()
+    try:
+        for rel, full in sorted(paths):
+            with open(full, "rb") as f:
+                data = f.read()
+            # lengths frame the stream: no two trees render alike
+            h.update(f"{rel}\0{len(data)}\0".encode())
+            h.update(data)
+    except OSError:
+        return None
+    return h.hexdigest()
+
+
+@functools.lru_cache(maxsize=1)
+def source_digest() -> Optional[str]:
+    """The digest of this package's source, read once per process: the part
+    of an entry's name that ties an executable to the code that compiled
+    it (module docstring). None when the source cannot be read; no kernel
+    is persisted then."""
+    import spark_rapids_tpu as package
+
+    try:
+        return _digest_tree(os.path.dirname(os.path.abspath(package.__file__)))
+    except Exception:  # noqa: BLE001 - no source = no store
+        return None
 
 
 # ── stable structural fingerprint ───────────────────────────────────────────
@@ -224,11 +278,15 @@ def _fingerprint(obj, out: list, depth: int = 0) -> None:
 
 
 def digest_for(key, sig) -> Optional[str]:
-    """SHA-256 hex entry name for a kernel's (cache key, jit arg signature),
-    or None when any component resists a stable rendering."""
+    """SHA-256 hex entry name for a kernel's (engine source, cache key, jit
+    arg signature), or None when the source cannot be read or any component
+    resists a stable rendering."""
+    source = source_digest()
+    if source is None:
+        return None
     out: list = []
     try:
-        _fingerprint((key, sig), out)
+        _fingerprint((source, key, sig), out)
     except _Unstable:
         return None
     except Exception:  # noqa: BLE001 - identity failure = safe miss
@@ -325,12 +383,14 @@ class XlaStore:
         nothing more."""
         from ..resilience import faults as _faults
 
+        # `source` is for an operator's triage and is not compared: the
+        # digest already holds it (module docstring)
         hdr = dict(fence=fence(), digest=digest, payload_len=len(payload),
-                   created=int(time.time()))
+                   created=int(time.time()), source=source_digest())
         if _faults.cache_stale_fence():
-            # chaos: an entry written by a "different engine revision" —
-            # the load path must fence it into a silent miss
-            hdr["fence"] = dict(hdr["fence"], schema_rev=SCHEMA_REV + 1_000_000)
+            # chaos: an entry written by "different software" — the load
+            # path must fence it into a silent miss
+            hdr["fence"] = dict(hdr["fence"], format=FORMAT_VERSION + 1_000_000)
         hbytes = json.dumps(hdr, sort_keys=True).encode("utf-8")
         blob = b"".join((
             MAGIC,

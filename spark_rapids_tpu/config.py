@@ -416,8 +416,8 @@ LEDGER_ENABLED = conf("spark.rapids.tpu.ledger.enabled").doc(
     "Host-overhead ledger (obs/ledger.py): decompose each query's wall "
     "clock into exhaustive non-overlapping phases (parse/plan, compile, "
     "h2d, dispatch, device wait, d2h, serialize, queue wait, glue "
-    "residual), exported via df.explain('metrics'), the per-query JSON "
-    "artifact, and the bench diag ranked breakdown."
+    "residual), exported via df.explain('metrics') and the per-query "
+    "JSON artifact."
 ).boolean_conf(True)
 
 CBO_CALIBRATION_ENABLED = conf("spark.rapids.tpu.cbo.calibration.enabled").doc(
@@ -507,8 +507,8 @@ PIPELINE_ENABLED = conf("spark.rapids.tpu.pipeline.enabled").doc(
     "pull at collect(), LIMIT's per-batch row-count sync) consume their "
     "upstream batch stream through a bounded prefetch window driven by a "
     "producer thread, so device work for batches i+1..k dispatches while "
-    "the sink blocks on batch i (kills the per-batch host-stall tax the "
-    "round-5 bench measured as host_overhead_frac 0.89-0.997). Kill "
+    "the sink blocks on batch i, in place of one host round trip per "
+    "batch with the device idle (gain not measured on the chip). Kill "
     "switch for the pipelined path; see docs/pipelined-execution.md."
 ).boolean_conf(True)
 
@@ -893,7 +893,8 @@ SERVE_HOST = conf("spark.rapids.tpu.serve.host").doc(
 
 SERVE_PORT = conf("spark.rapids.tpu.serve.port").doc(
     "TCP port for the serving endpoint; 0 picks an ephemeral port "
-    "(reported by TpuServer.start(), the test/bench mode)."
+    "(reported by TpuServer.start(); what the tests and the benchmark "
+    "use)."
 ).int_conf(8045)
 
 SERVE_TENANTS = conf("spark.rapids.tpu.serve.tenants").doc(
@@ -1097,8 +1098,8 @@ FAULTS_CACHE_CORRUPT_EVERY_N = conf(
 FAULTS_CACHE_STALE_VERSION_EVERY_N = conf(
     "spark.rapids.tpu.faults.compileCache.staleVersionEveryN"
 ).doc(
-    "Write every Nth compile-cache entry with a perturbed engine schema "
-    "revision in its header — the version fence must turn it into a "
+    "Write every Nth compile-cache entry with a perturbed format "
+    "version in its header — the version fence must turn it into a "
     "SILENT miss (no load attempt, no quarantine); 0 disables."
 ).int_conf(0)
 

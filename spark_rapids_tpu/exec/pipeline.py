@@ -6,7 +6,7 @@ engine's operator chains are *pull-based* generators: batch i+1's kernels
 are not even dispatched until the consumer finishes with batch i. Every
 blocking sink — the D2H pull at collect(), a LIMIT's per-batch row-count
 sync — therefore idles the device for a full host round trip per batch
-(BENCH_r05: ``host_overhead_frac`` 0.89-0.997 on nearly every TPC-H query).
+(what that costs a query has not been measured on the chip).
 The reference never pays this: cuDF streams batches through the plan with
 no per-op host syncs (PAPER L0/L1).
 

@@ -962,9 +962,7 @@ class TpuHashAggregateExec(Exec):
 
             return _width
 
-        # retagged with the packed sort key: the executable store fences by
-        # jax's version alone (ROADMAP D15) and would serve the older program
-        key = ("agg_width_k32", grouping, child_schema, pre_filter, has_nans)
+        key = ("agg_width", grouping, child_schema, pre_filter, has_nans)
         return K.key_sort_kernel(key, make)
 
     def _fused_child(self) -> tuple:
@@ -1199,7 +1197,7 @@ def aggregate_kernel(
         return _aggregate
 
     key = (
-        "agg_k32",  # retagged with the packed sort key (D15), as "agg_width_k32"
+        "agg",
         mode,
         grouping,
         agg_fns,
@@ -1241,7 +1239,7 @@ def aggregate_merge_kernel(
         return _m
 
     return K.key_sort_kernel(
-        ("agg_merge_k32", grouping, agg_fns, out_schema, has_nans), make
+        ("agg_merge", grouping, agg_fns, out_schema, has_nans), make
     )
 
 
@@ -1372,7 +1370,7 @@ def device_sort_fn(order: List[SortOrder]):
 
         return _sort
 
-    return K.key_sort_kernel(("sort_k32", _order_key(order)), make)
+    return K.key_sort_kernel(("sort", _order_key(order)), make)
 
 
 def device_merge_fn(order: List[SortOrder]):

@@ -110,10 +110,7 @@ class TpuWindowExec(Exec):
         out_schema = self._schema
         from .. import kernels as K
 
-        # a tag per program: "window" while the jitted function was `fn`,
-        # "window_named" with uint64 radix words; a store that holds those
-        # modules must not serve them for this one (ROADMAP D15)
-        key = ("window_k32", pkeys, orders, window_cols, out_schema, child_schema)
+        key = ("window", pkeys, orders, window_cols, out_schema, child_schema)
         return K.key_sort_kernel(
             key,
             lambda: _make_window_kernel(

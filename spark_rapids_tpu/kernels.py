@@ -681,8 +681,8 @@ def compile_cache_root() -> str:
 
 
 def enable_persistent_cache() -> None:
-    """Turn on JAX's on-disk compilation cache so separate processes (bench
-    runs, chip calls) reuse XLA executables. With
+    """Turn on JAX's on-disk compilation cache so separate processes
+    (restarts, benchmark runs, chip calls) reuse XLA executables. With
     ``JAX_COMPILATION_CACHE_DIR`` set, jax has already put its cache there
     and the directory is left alone; unset, it goes to ``<root>/jax``. A
     cache that cannot be set up raises."""

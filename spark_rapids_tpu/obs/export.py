@@ -5,10 +5,10 @@ the reference, re-targeted at TPU ops tooling):
 
 - :func:`prometheus_text` — the process-wide registry plus the last plan's
   per-operator metrics in Prometheus text exposition format (scrape it, or
-  dump it next to a bench run);
+  dump it next to a run);
 - :func:`query_artifact` / :func:`write_query_artifact` — one JSON document
   per query: per-node metrics, pipeline health, resilience counters, and
-  the session registry snapshot (machine-readable bench/CI diffing);
+  the session registry snapshot (machine-readable, for diffing in CI);
 - :func:`render_plan_metrics` — the ``df.explain("metrics")`` renderer:
   per-op metrics inline on the physical plan tree, nanos rendered as ms
   (the reference's SQL-UI node annotations).
@@ -97,8 +97,8 @@ def metrics_report(plan) -> str:
 
 
 def device_host_breakdown(plan) -> dict:
-    """Aggregate totals for the bench JSON ``detail``: device-attributed
-    op time vs host transfer time vs rows moved."""
+    """Aggregate totals for the query artifact's ``breakdown``:
+    device-attributed op time vs host transfer time vs rows moved."""
     out = {
         "op_time_ms": 0.0,
         "h2d_time_ms": 0.0,
@@ -129,8 +129,8 @@ def device_host_breakdown(plan) -> dict:
 
 
 def pipeline_report(plan) -> dict:
-    """Dispatch-ahead pipeline health for the bench ``diag`` block
-    (exec/pipeline.py feeds the ``pipe*`` metrics):
+    """Dispatch-ahead pipeline health for the query artifact's ``pipeline``
+    block (exec/pipeline.py feeds the ``pipe*`` metrics):
 
     * ``dispatch_depth`` — deepest in-flight window observed at any
       pipelined sink (0 = pipeline never engaged);

@@ -1,9 +1,10 @@
 """Host-overhead ledger — per-query wall clock decomposed into exhaustive,
 non-overlapping phases.
 
-The r05 bench can say host time dominates (``host_overhead_frac`` ≥ 0.92 on
-20/22 TPC-H queries) but not WHERE it goes; this module is the answer
-machine. One :class:`PhaseLedger` per query accumulates exclusive
+A wall clock can say that host time dominates a query but not WHERE it
+goes; this module is the answer machine (``benchmark/run.py`` reads
+``plan_ms.*`` and ``h2d_ms.batch`` from it). One :class:`PhaseLedger` per
+query accumulates exclusive
 nanoseconds per phase:
 
     ``parse_plan``   — analysis + physical planning + overrides

@@ -183,7 +183,7 @@ class Histogram(Metric):
     ``value`` is the observation COUNT (so generic exporters render
     something sane); ``add``/``timed()`` observe, so a Histogram drops in
     anywhere a NANOS timer was fed durations. ``state()`` snapshots
-    ``(counts, sum, count)`` for delta-based percentile math (bench
+    ``(counts, sum, count)`` for delta-based percentile math (a run's
     phases)."""
 
     N_BUCKETS = 64
@@ -224,7 +224,7 @@ class Histogram(Metric):
 
 def histogram_delta(after: tuple, before: tuple) -> tuple:
     """``after - before`` of two Histogram.state() snapshots — the windowed
-    view bench phases use (percentiles of only this run's observations)."""
+    view a measured phase uses (percentiles of only this run's observations)."""
     ca, sa, na = after
     cb, sb, nb = before
     return (

@@ -2,7 +2,7 @@
 metric registry.
 
 ``/metrics`` answers Prometheus text exposition (the same document
-``obs.export.prometheus_text`` writes next to bench runs, but LIVE — a
+``obs.export.prometheus_text`` renders, but LIVE — a
 scraper watches compile counters climb while a query runs); ``/healthz``
 answers a small JSON liveness document, with readiness/draining folded in
 when the endpoint fronts a :class:`~spark_rapids_tpu.serve.TpuServer`.

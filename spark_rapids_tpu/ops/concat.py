@@ -113,10 +113,7 @@ def concat_device(batches: list[DeviceBatch]) -> DeviceBatch:
 
         return K.GuardedJit(_concat)
 
-    # the tag is part of the executable store's key (cache/xla_store.py),
-    # whose fence knows nothing of this file: "concat" is the index-scatter
-    # program of earlier checkouts, which may share the store's directory
-    fn = K.kernel(("concat_copy", schema, shapes, cap), build)
+    fn = K.kernel(("concat", schema, shapes, cap), build)
     return fn(tuple(batches))
 
 

@@ -58,9 +58,7 @@ def shrink_one(batch: DeviceBatch, n: int, tight: bool = True) -> DeviceBatch:
 
         return K.GuardedJit(_shrink)
 
-    # "shrink" is the same program under the name jit__lambda, which an
-    # executable store filled by an earlier checkout would hand back
-    fn = K.kernel(("shrink_rows", batch.schema, batch.capacity, cap2), build)
+    fn = K.kernel(("shrink", batch.schema, batch.capacity, cap2), build)
     return fn(batch)
 
 

@@ -3,8 +3,8 @@
 The reference accelerator owns the physical plan, so it owns execution
 granularity too (PAPER.md); this pass spends that ownership. The per-op
 execution model launches one jitted program per project/filter node per
-batch, and BENCH_r05's attribution ledger showed the launches themselves —
-dispatch + glue, not device compute — dominating 20/22 TPC-H queries. A
+batch, so a chain of n operators pays n dispatches and n rounds of glue
+for a batch (their share of a query: not measured on the chip). A
 *stage* is a maximal chain of adjacent device row-operators whose bodies
 are pure expression evaluation; fusing the chain stitches their expression
 trees end-to-end inside ONE jitted function, so a batch pays one dispatch

@@ -540,7 +540,7 @@ class WriteFiles(LogicalPlan):
 def output_round_columns(plan: LogicalPlan):
     """Indices of output columns tainted by a float ``round()``/``bround()``
     — the column either computes one or references a child column that
-    does. Scopes the bench/differential float slack to only the columns
+    does. Scopes the differential tests' float slack to only the columns
     the incompat device round can actually perturb (a device bug in an
     UNROUNDED column must not ride the tolerance). Returns None when the
     taint cannot be tracked (round hidden under a plan shape this walk

@@ -883,8 +883,8 @@ def _plan_join(lp: L.Join, conf: TpuConf) -> Exec:
 #
 # The reference never compiles at query time: cuDF ships pre-built kernels.
 # The TPU engine's first touch of each operator pays an XLA compile instead,
-# and those compiles SERIALIZE down the pull-based operator chain (the
-# round-5 bench measured 18-64s of first-run compile per query). This pass
+# and those compiles SERIALIZE down the pull-based operator chain (cold
+# set-up on the chip: PERF.md section 7). This pass
 # walks the final (device) exec tree right after planning, derives the exact
 # batch geometry of the shape-predictable scan-side chains, and warms every
 # distinct kernel through kernels.precompile — concurrently where the

@@ -11,7 +11,7 @@ report functions). PR 4 moved the machinery into the unified subsystem:
 Everything importable from here before PR 4 still is — ``walk``,
 ``instrument_plan``, ``query_trace``, ``metrics_report``,
 ``pipeline_report``, ``resilience_report``, ``device_host_breakdown`` —
-now as thin shims, so bench rigs and tests written against the old surface
+now as thin shims, so rigs and tests written against the old surface
 keep working. What stays native here is the jax.profiler integration
 (XPlane/TensorBoard capture + the block-until-ready opTime debug mode),
 which is TPU-runtime-specific rather than part of the portable obs layer.

@@ -7,7 +7,7 @@ Four pillars:
 
 * ``retry``   — OOM classification (cause-chain walk), the spill → retry →
   split-in-half state machine splittable operators opt into, and the
-  process-wide resilience counters the bench diag reports.
+  process-wide resilience counters the query artifact reports.
 * ``breaker`` — CPU-fallback circuit breaker: repeated non-OOM device
   failures per op signature flip that op to CPU for the session.
 * ``faults``  — deterministic, seeded fault injection (device OOM, compile

@@ -217,8 +217,8 @@ class FaultInjector:
 
     # ── compile-cache damage points (cache/xla_store.py) ────────────────
     def cache_stale_fence(self) -> bool:
-        """Whether this entry's header should carry a perturbed engine
-        schema revision (version-skew simulation — the load fence must
+        """Whether this entry's header should carry a perturbed format
+        version (version-skew simulation — the load fence must
         silently miss it)."""
         if self._tick("cache_stale_version",
                       self.config.cache_stale_version_every_n):

@@ -79,7 +79,7 @@ def is_device_error(err: BaseException) -> bool:
     return False
 
 
-# ── retry counters (the bench / profiling diag block) ──────────────────────
+# ── retry counters (the profiling diag block) ──────────────────────────────
 # Counters live in the process-wide typed registry (obs/metrics.py) under
 # the ``resilience.`` prefix; ``report()`` is a registry view. The catalog
 # pre-registers the well-known names so a healthy run still exports the
@@ -96,7 +96,7 @@ def record(name: str, n: int = 1) -> None:
 
 
 def report() -> dict:
-    """Cumulative process-wide resilience counters (profiling / bench) —
+    """Cumulative process-wide resilience counters (profiling) —
     a view over the registry's ``resilience.`` slice."""
     return _REGISTRY.view("resilience.")
 

@@ -355,7 +355,7 @@ class QueryScheduler:
         return sum(1 for a in admissions if a.token.cancel(reason))
 
     def state(self) -> dict:
-        """One snapshot for bench/diagnostics: pool occupancy + the
+        """One snapshot for diagnostics: pool occupancy + the
         scheduler slice of the process metric registry."""
         with self._lock:
             n_active = len(self._active)

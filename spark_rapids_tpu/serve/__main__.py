@@ -36,7 +36,7 @@ def main(argv=None) -> int:
                     "(spark.rapids.tpu.serve.tenants)")
     ap.add_argument("--tpch-sf", type=float, default=0.0,
                     help="register the TPC-H tables at this scale factor "
-                    "as temp views (demo/bench catalog)")
+                    "as temp views (demo catalog)")
     ap.add_argument("--warm-tpch", action="store_true",
                     help="precompile TPC-H q1/q6 before reporting ready "
                     "(requires --tpch-sf)")

@@ -313,8 +313,8 @@ class TpuServer:
         #: that stop one tenant wedging the accept loop for everyone)
         self._tenant_conns: Dict[str, int] = {}  # graft: guarded_by(_conn_lock)
         self._tenant_inflight: Dict[str, int] = {}  # graft: guarded_by(_inflight_cond)
-        #: (tenant, wait_s, run_s) per served query — the SLO bench's
-        #: percentile source (bounded; aggregate totals live in serve.*)
+        #: (tenant, wait_s, run_s) per served query — a bounded window
+        #: for debugging (aggregate totals live in serve.*)
         self.latency_samples: deque = deque(maxlen=8192)
         #: failover dedup window: client-generated dedup keys recently
         #: seen, bounded LRU sized by serve.failover.dedupWindow. A key
@@ -1218,8 +1218,8 @@ class TpuServer:
             _M.timer("serve.queryWaitNs").add(adm.queue_wait_ns)
             run_ns = time.perf_counter_ns() - t0 - adm.queue_wait_ns
             _M.timer("serve.queryRunNs").add(max(0, run_ns))
-            # the distribution series (log2-bucket histograms): what the
-            # SLO bench derives its p50/p95/p99 from now
+            # the distribution series (log2-bucket histograms): what a
+            # p50/p95/p99 of the server's own wait and run time is read from
             _M_WAIT_HIST.observe(adm.queue_wait_ns)
             _M_RUN_HIST.observe(max(0, run_ns))
             _M_TOTAL_HIST.observe(adm.queue_wait_ns + max(0, run_ns))

@@ -112,8 +112,8 @@ class TpuSession:
             # single-device default: ONE task (the reference's
             # concurrentGpuTasks model). Without mesh mode every partition
             # runs serialized on the default device — each extra partition
-            # is another kernel pipeline + host sync, measured 2-4x slower
-            # at partitions=2 vs 1 on the bench queries. Mesh mode above
+            # is another kernel pipeline + host sync (not measured on the
+            # chip). Mesh mode above
             # sets one partition per chip instead.
             self.conf = self.conf.set(cfg.SHUFFLE_PARTITIONS.key, 1)
         self.read = DataFrameReader(self)

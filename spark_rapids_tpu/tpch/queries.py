@@ -7,7 +7,8 @@ subqueries); Q11's fraction is the spec's ``0.0001 / SF``.
 
 The reference has no TPC-H rig to cite; its QA analogue is the nightly SQL
 battery (integration_tests/src/main/python/qa_nightly_sql.py). These
-translations are the device-plan workloads bench.py measures.
+translations are the device-plan workloads of the differential tests
+(tests/test_tpch.py); the benchmark (benchmark/queries/) carries its own.
 """
 from __future__ import annotations
 

@@ -211,13 +211,6 @@ def test_tpch_ledger_exhaustive_and_ranked():
         # the documented decomposition keys only
         assert set(bd["phases_ms"]) <= set(OL.PHASES), bd
 
-    # bench-diag integration: the ranked breakdown rides plan_diagnostics
-    import importlib
-
-    bench = importlib.import_module("bench")
-    diag = bench.plan_diagnostics(s, wall_s=1.0)
-    assert "ledger" in diag and "phases_ms" in diag["ledger"]
-
 
 def test_ledger_in_explain_and_artifact(tmp_path):
     s = tpu_session(strict=False)

@@ -2,8 +2,8 @@
 from SQL text through the sql/ front-end (the north-star workload —
 BASELINE.json: TPC-DS, 99 queries; VERDICT r4 item 1).
 
-Tiny scale factor keeps the suite tractable on this box; bench.py runs the
-same query texts at real scale on hardware (``--suite tpcds``). Device
+Tiny scale factor keeps the suite tractable on this box; on the chip the
+benchmark (benchmark/run.py) runs q67 alone. Device
 placement is asserted the same way test_tpch.py does: the only nodes off
 device may be source scans (host Arrow decode is the v1 I/O design).
 """

@@ -3,8 +3,8 @@
 The reference's closest analogue is its nightly SQL battery + mortgage ETL
 suite (integration_tests qa_nightly_sql.py, mortgage/Benchmarks.scala); the
 TPC-H rig itself is this framework's own (BASELINE.md's north star is
-TPC-shaped). Tiny scale factor keeps the suite fast; bench.py runs the same
-queries at real scale on hardware.
+TPC-shaped). Tiny scale factor keeps the suite fast; the benchmark
+(benchmark/run.py) runs q1, q3 and q6 at SF 1 on the chip.
 """
 from __future__ import annotations
 

@@ -363,16 +363,14 @@ def _window_kernels():
 
 
 def test_window_kernel_name_and_store_key():
-    """A device trace names the module after the jitted function, and
-    cache/xla_store.py keys a stored executable by the kernel key and fences
-    by jax's version only: under an old tag a store filled by an earlier
-    checkout would hand back the module still named ``jit_fn``, or the one
-    that sorts by uint64 radix words."""
+    """The key's tag names the kernel (whether a stored executable is stale
+    is cache/xla_store.py's to decide, from the source), and a device trace
+    names the module after the jitted function."""
     t = _table(60)
     s = tpu_session()
     s.create_dataframe(t).with_column("r", F.rank().over(_w())).collect()
     mine = _window_kernels()
-    assert mine and {k[0] for k in mine} == {"window_k32"}
+    assert mine and {k[0] for k in mine} == {"window"}
     assert {fn._fn.__name__ for fn in mine.values()} == {"_window"}
 
 

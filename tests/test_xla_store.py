@@ -13,6 +13,8 @@ from __future__ import annotations
 import glob
 import os
 import struct
+import subprocess
+import sys
 import threading
 import time
 import zlib
@@ -124,8 +126,8 @@ def test_bit_flip_in_header_quarantines_without_parsing(store):
 
 
 def test_version_fence_is_a_silent_miss_never_a_load(store):
-    """An entry written by a 'different engine revision' (stale-fence
-    injection) silently misses: no quarantine, no corrupt count, and the
+    """An entry written by 'different software' (stale-fence injection: a
+    format this code does not write) silently misses: no quarantine, no corrupt count, and the
     payload is never parsed — the entry just ages out through LRU."""
     digest = "f" * 64
     inj = F.FaultInjector(F.FaultConfig(cache_stale_version_every_n=1))
@@ -242,6 +244,162 @@ def test_digest_hashes_full_ndarray_buffer_not_its_elided_repr():
     da = xc.digest_for(("k", a), ("s",))
     db = xc.digest_for(("k", b), ("s",))
     assert da and db and da != db
+
+
+# ── the engine's source in the entry's name ─────────────────────────────────
+
+def _as_source(monkeypatch, digest):
+    """Run what follows as the checkout whose source digests to ``digest``
+    (``raising=False``: at a commit without the function the tests below
+    fail on what they assert, not on the patch)."""
+    monkeypatch.setattr(xc, "source_digest", lambda: digest, raising=False)
+    K.clear()
+
+
+def test_digest_names_the_source(monkeypatch):
+    key, sig = ("project", 1, "a"), (("treedef",), ((4,), "float32"))
+    _as_source(monkeypatch, "a" * 64)
+    da = xc.digest_for(key, sig)
+    _as_source(monkeypatch, "b" * 64)
+    db = xc.digest_for(key, sig)
+    assert da and db and da != db
+    _as_source(monkeypatch, "a" * 64)
+    assert xc.digest_for(key, sig) == da
+
+
+def test_two_checkouts_share_one_store_side_by_side(engine_store, monkeypatch):
+    """The benchmark driver's case: a parent and a change alternate over one
+    directory. Each side's entry stays on disk and loads only under its own
+    source: neither evicts or overwrites the other's."""
+    def make():
+        return K.GuardedJit(lambda x: x * 3 + 1)
+
+    x = np.arange(32, dtype=np.int64)
+    ref = (x * 3 + 1).tolist()
+    for side in ("a" * 64, "b" * 64):
+        _as_source(monkeypatch, side)
+        m0 = _counter("cache.xla.miss")
+        assert np.asarray(K.kernel(("xc-shared", 1), make)(x)).tolist() == ref
+        assert _counter("cache.xla.miss") == m0 + 1
+    entries = sorted(glob.glob(os.path.join(engine_store.root, "*.xc")))
+    assert len(entries) == 2
+    before = [(e, open(e, "rb").read()) for e in entries]
+    sources = {xc.XlaStore._parse(blob)[0]["source"] for _, blob in before}
+    assert sources == {"a" * 64, "b" * 64}, "the header names the source"
+    # second and later runs of both sides, in the driver's order: all hits
+    s0 = _counter("cache.xla.stores")
+    for side in ("a" * 64, "b" * 64, "b" * 64, "a" * 64):
+        _as_source(monkeypatch, side)
+        h0, m0 = _counter("cache.xla.hit"), _counter("cache.xla.miss")
+        assert np.asarray(K.kernel(("xc-shared", 1), make)(x)).tolist() == ref
+        assert _counter("cache.xla.hit") == h0 + 1
+        assert _counter("cache.xla.miss") == m0
+    assert _counter("cache.xla.stores") == s0, "nothing was written again"
+    assert [(e, open(e, "rb").read()) for e in entries] == before
+
+
+def test_changed_body_under_unchanged_key_compiles_anew(
+    engine_store, monkeypatch
+):
+    """A kernel's body changes; its cache key and tag do not. The store an
+    earlier checkout filled must not answer for it."""
+    x = np.arange(32, dtype=np.int64)
+    _as_source(monkeypatch, "a" * 64)
+    old = K.kernel(("xc-body", 1), lambda: K.GuardedJit(lambda x: x * 3 + 1))
+    assert np.asarray(old(x)).tolist() == (x * 3 + 1).tolist()
+    _as_source(monkeypatch, "b" * 64)
+    h0, m0 = _counter("cache.xla.hit"), _counter("cache.xla.miss")
+    new = K.kernel(("xc-body", 1), lambda: K.GuardedJit(lambda x: x * 5 + 2))
+    assert np.asarray(new(x)).tolist() == (x * 5 + 2).tolist()
+    assert _counter("cache.xla.hit") == h0
+    assert _counter("cache.xla.miss") == m0 + 1
+    assert engine_store.stats()["entries"] == 2
+
+
+def _tree(root, files):
+    for rel, data in files.items():
+        path = os.path.join(root, rel)
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        with open(path, "wb") as f:
+            f.write(data)
+    return str(root)
+
+
+_TREE = {"__init__.py": b"", "ops/a.py": b"x = 1\n", "ops/b.py": b"y = 2\n"}
+
+
+def test_tree_digest_is_stable(tmp_path):
+    one = _tree(tmp_path / "one", _TREE)
+    two = _tree(tmp_path / "two", dict(reversed(list(_TREE.items()))))
+    assert xc._digest_tree(one) == xc._digest_tree(one) == xc._digest_tree(two)
+
+
+@pytest.mark.parametrize("edit", ["byte", "rename", "move_bytes", "new_file"])
+def test_tree_digest_changes_with_the_source(tmp_path, edit):
+    files = dict(_TREE)
+    if edit == "byte":
+        files["ops/a.py"] = b"x = 2\n"
+    elif edit == "rename":
+        files["ops/c.py"] = files.pop("ops/a.py")
+    elif edit == "move_bytes":  # the same bytes, split between files elsewhere
+        files["ops/a.py"], files["ops/b.py"] = b"x = 1\ny", b" = 2\n"
+    else:
+        files["ops/d.py"] = b""
+    assert xc._digest_tree(_tree(tmp_path / "a", _TREE)) != xc._digest_tree(
+        _tree(tmp_path / "b", files)
+    )
+
+
+def test_tree_digest_ignores_pycache_and_other_files(tmp_path):
+    extra = dict(_TREE)
+    extra["ops/__pycache__/a.cpython-312.pyc"] = b"\x00" * 8
+    extra["ops/__pycache__/stray.py"] = b"z = 3\n"
+    extra["native/lib.so"] = b"\x7fELF"
+    assert xc._digest_tree(_tree(tmp_path / "a", _TREE)) == xc._digest_tree(
+        _tree(tmp_path / "b", extra)
+    )
+
+
+def test_source_digest_is_the_packages_and_the_same_in_another_process():
+    import spark_rapids_tpu
+
+    here = xc.source_digest()
+    assert here and here == xc.source_digest()
+    assert here == xc._digest_tree(os.path.dirname(spark_rapids_tpu.__file__))
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "from spark_rapids_tpu.cache import xla_store as xc;"
+         "print(xc.source_digest())"],
+        capture_output=True, text=True, timeout=240, check=True,
+        cwd=os.path.dirname(os.path.dirname(spark_rapids_tpu.__file__)),
+    )
+    assert out.stdout.split()[-1] == here
+
+
+@pytest.mark.parametrize("how", ["no_source", "unreadable", "missing"])
+def test_unreadable_package_has_no_digest(tmp_path, how):
+    root = tmp_path / "pkg"
+    if how == "no_source":  # an import from an archive: a directory of .pyc
+        _tree(root, {"__pycache__/a.pyc": b"\x00", "data.bin": b"\x01"})
+    elif how == "unreadable":
+        _tree(root, _TREE)
+        os.symlink(str(root / "gone.py"), str(root / "ops" / "dangling.py"))
+    assert xc._digest_tree(str(root)) is None
+
+
+def test_without_a_source_digest_kernels_stay_memory_only(
+    engine_store, monkeypatch
+):
+    _as_source(monkeypatch, None)
+    assert xc.digest_for(("project", 1), ("s",)) is None
+    x = np.arange(8, dtype=np.int64)
+    s0, m0 = _counter("cache.xla.stores"), _counter("cache.xla.miss")
+    g = K.kernel(("xc-nosrc", 1), lambda: K.GuardedJit(lambda x: x - 4))
+    assert np.asarray(g(x)).tolist() == (x - 4).tolist()
+    assert np.asarray(g(x)).tolist() == (x - 4).tolist()
+    assert engine_store.stats()["entries"] == 0
+    assert _counter("cache.xla.stores") == s0
+    assert _counter("cache.xla.miss") == m0, "the store is never consulted"
 
 
 # ── deserialize-failure breaker ─────────────────────────────────────────────
