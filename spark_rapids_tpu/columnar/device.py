@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
+import pyarrow.compute as pc
 
 from ..types import (
     DataType,
@@ -218,30 +219,73 @@ class DeviceBatch:
 #    from(Table)/from(ColumnarBatch) + RapidsHostColumnVector) ───────────────
 
 
-def _np_from_arrow_fixed(arr: pa.Array, dt: DataType) -> tuple[np.ndarray, np.ndarray]:
-    """Arrow fixed-width array → (data ndarray, validity ndarray), nulls
-    zeroed. Buffer-view based (no float64 round trip) — see host.np_from_arrow."""
-    from .host import np_from_arrow
+def _padded_validity(arr: pa.Array, cap: int) -> tuple[np.ndarray, Optional[np.ndarray]]:
+    """Validity plane of ``arr`` at capacity (padding rows False), and the
+    live rows that are null as a mask — None where the array has none, so
+    the planes of a column without nulls are written with no mask pass."""
+    n = len(arr)
+    pval = np.empty(cap, dtype=bool)
+    pval[n:] = False
+    if arr.null_count == 0:
+        pval[:n] = True
+        return pval, None
+    pval[:n] = np.asarray(arr.is_valid())
+    return pval, ~pval[:n]
 
-    return np_from_arrow(arr, dt)
+
+def _fixed_to_padded(arr: pa.Array, dt: DataType, cap: int) -> tuple[np.ndarray, np.ndarray]:
+    """Arrow fixed-width array → (data[cap], validity[cap]), null slots and
+    padding rows zero: the buffer view host.np_from_arrow (the CPU engine's
+    route) masks and returns is here copied once, into the plane that ships."""
+    from .host import fixed_view
+
+    n = len(arr)
+    pval, nulls = _padded_validity(arr, cap)
+    pdata = np.empty(cap, dtype=dt.np_dtype)
+    pdata[:n] = fixed_view(arr, dt)
+    pdata[n:] = 0
+    if nulls is not None:
+        np.putmask(pdata[:n], nulls, 0)
+    return pdata, pval
+
+
+# rows of a ragged string plane padded per pyarrow call: bounds the padded
+# temporary (and keeps rows * width inside a string array's int32 offsets)
+_PAD_CHUNK_BYTES = 1 << 22
 
 
 def _string_to_padded(
-    arr: pa.Array, width: Optional[int], max_str_bytes: Optional[int] = None
-) -> tuple[np.ndarray, np.ndarray, np.ndarray, int]:
-    """Arrow string array → (bytes[n, width], lengths[n], validity[n], width).
-    ``max_str_bytes`` (spark.rapids.tpu.string.maxBytes) caps the inferred
-    width — longer values raise, surfacing the configured ceiling."""
+    arr: pa.Array,
+    cap: int,
+    width: Optional[int] = None,
+    max_str_bytes: Optional[int] = None,
+) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Arrow string array → (bytes[cap, width], lengths[cap], validity[cap]),
+    each plane allocated at capacity and written once: bytes past a value's
+    length, null slots and padding rows are zero. ``max_str_bytes``
+    (spark.rapids.tpu.string.maxBytes) caps the inferred width — longer
+    values raise, surfacing the configured ceiling.
+
+    The fill follows what the array shows. Where every value has one length
+    L and none is null (char(n) columns), the value buffer IS a [rows, L]
+    matrix and one strided copy places it. Otherwise pyarrow right-pads the
+    values to ``width`` with NUL bytes, a chunk of rows at a time, which
+    makes the same matrix of each chunk."""
+    from ..obs.metrics import GLOBAL as _M
+
     arr = arr.cast(pa.string())
     n = len(arr)
-    valid = ~np.asarray(arr.is_null())
+    pval, nulls = _padded_validity(arr, cap)
     # Offsets/values buffers give us lengths without python-object round trips.
-    buf_offsets = np.frombuffer(arr.buffers()[1], dtype=np.int32)[
+    offsets = np.frombuffer(arr.buffers()[1], dtype=np.int32)[
         arr.offset : arr.offset + n + 1
     ]
-    lengths = (buf_offsets[1:] - buf_offsets[:-1]).astype(np.int32)
-    lengths = np.where(valid, lengths, 0).astype(np.int32)
-    maxlen = int(lengths.max()) if n else 0
+    plen = np.empty(cap, dtype=np.int32)
+    plen[n:] = 0
+    np.subtract(offsets[1:], offsets[:-1], out=plen[:n])
+    if nulls is not None:
+        np.putmask(plen[:n], nulls, 0)
+    maxlen = int(plen[:n].max()) if n else 0
     if width is None:
         if max_str_bytes is not None and maxlen > max_str_bytes:
             raise ValueError(
@@ -251,18 +295,31 @@ def _string_to_padded(
         width = bucket_width(max(maxlen, 1))
     if maxlen > width:
         raise ValueError(f"string length {maxlen} exceeds device width {width}")
-    out = np.zeros((n, width), dtype=np.uint8)
-    values = np.frombuffer(arr.buffers()[2], dtype=np.uint8) if arr.buffers()[2] else np.zeros(0, np.uint8)
-    # Vectorized ragged copy: gather value bytes into the padded matrix.
-    starts = buf_offsets[:-1]
-    cols = np.arange(width, dtype=np.int64)[None, :]
-    idx = starts.astype(np.int64)[:, None] + cols
-    take_mask = cols < lengths[:, None]
-    idx = np.where(take_mask, idx, 0)
-    if values.size:
-        gathered = values[np.clip(idx, 0, values.size - 1)]
-        out = np.where(take_mask, gathered, 0).astype(np.uint8)
-    return out, lengths, valid, width
+    _M.counter("batch.padStringPlanes").add(1)
+    pdata = np.empty((cap, width), dtype=np.uint8)
+    pdata[n:] = 0
+    if maxlen == 0:  # empty, all null or all "": the values buffer may be absent
+        pdata[:n] = 0
+        return pdata, plen, pval
+    values = arr.buffers()[2]
+    first = int(offsets[0])
+    if nulls is None and int(offsets[-1]) - first == n * maxlen:
+        _M.counter("batch.padStringPlanesFixedLen").add(1)
+        flat = np.frombuffer(values, dtype=np.uint8)[first : first + n * maxlen]
+        pdata[:n, :maxlen] = flat.reshape(n, maxlen)
+        pdata[:n, maxlen:] = 0
+        return pdata, plen, pval
+    # a null pads as "": Arrow lets a null slot span value bytes, of any length
+    dense = arr.fill_null("") if nulls is not None else arr
+    step = max(1, _PAD_CHUNK_BYTES // width)
+    for lo in range(0, n, step):
+        rows = min(step, n - lo)
+        padded = pc.ascii_rpad(dense.slice(lo, rows), width=width, padding="\0")
+        start = int(np.frombuffer(padded.buffers()[1], dtype=np.int32)[padded.offset])
+        pdata[lo : lo + rows] = np.frombuffer(padded.buffers()[2], dtype=np.uint8)[
+            start : start + rows * width
+        ].reshape(rows, width)
+    return pdata, plen, pval
 
 
 def _padded_to_string(data: np.ndarray, lengths: np.ndarray, valid: np.ndarray, n: int) -> pa.Array:
@@ -294,13 +351,7 @@ def _np_col_from_arrow(
 
     n = len(arr)
     if isinstance(dt, StringType):
-        data, lengths, valid, w = _string_to_padded(arr, width, max_str_bytes)
-        pdata = np.zeros((cap, w), dtype=np.uint8)
-        pdata[:n] = data
-        plen = np.zeros(cap, dtype=np.int32)
-        plen[:n] = lengths
-        pval = np.zeros(cap, dtype=bool)
-        pval[:n] = valid
+        pdata, plen, pval = _string_to_padded(arr, cap, width, max_str_bytes)
         return DeviceColumn(dt, pdata, pval, plen)
     if isinstance(dt, NullType):
         return DeviceColumn(dt, np.zeros(cap, np.int8), np.zeros(cap, bool))
@@ -315,11 +366,7 @@ def _np_col_from_arrow(
         return DeviceColumn(dt, None, pval, None, kids)
     if isinstance(dt, (ArrayType, MapType)):
         return _np_list_from_arrow(arr, dt, cap)
-    data, valid = _np_from_arrow_fixed(arr, dt)
-    pdata = np.zeros(cap, dtype=dt.np_dtype)
-    pdata[:n] = data
-    pval = np.zeros(cap, dtype=bool)
-    pval[:n] = valid
+    pdata, pval = _fixed_to_padded(arr, dt, cap)
     return DeviceColumn(dt, pdata, pval)
 
 

@@ -35,6 +35,26 @@ def fixed_np(arr: pa.Array, np_dtype: np.dtype) -> np.ndarray:
     return data
 
 
+def fixed_view(arr: pa.Array, dt: DataType) -> np.ndarray:
+    """A fixed-width arrow array's values as ``dt.np_dtype``, viewed in place
+    where the buffer's layout allows (decimals are a strided view, bools are
+    unpacked). Null slots hold whatever the buffer holds — callers mask them."""
+    n = len(arr)
+    if isinstance(dt, DecimalType):
+        # decimal128 storage is 128-bit little-endian; DECIMAL64 gating means
+        # the value always fits the low 64 bits (two's complement)
+        buf = arr.buffers()[1]
+        if buf is None:
+            return np.zeros(n, dtype=np.int64)
+        pairs = np.frombuffer(buf, dtype=np.int64, count=(arr.offset + n) * 2)
+        return pairs.reshape(-1, 2)[arr.offset :, 0]
+    if pa.types.is_date32(arr.type):
+        arr = arr.cast(pa.int32())
+    elif pa.types.is_timestamp(arr.type):
+        arr = arr.cast(pa.int64())
+    return fixed_np(arr, dt.np_dtype)
+
+
 def np_from_arrow(arr: pa.Array, dt: DataType) -> tuple[np.ndarray, np.ndarray]:
     """Arrow array → (data, validity). For strings, data is an object ndarray
     of python str (None for null). Null slots in fixed-width data are zeroed."""
@@ -54,20 +74,9 @@ def np_from_arrow(arr: pa.Array, dt: DataType) -> tuple[np.ndarray, np.ndarray]:
         return data, valid
     if isinstance(dt, NullType):
         return np.zeros(n, dtype=np.int8), np.zeros(n, dtype=bool)
+    data = fixed_view(arr, dt)
     if isinstance(dt, DecimalType):
-        # decimal128 storage is 128-bit little-endian; DECIMAL64 gating means
-        # the value always fits the low 64 bits (two's complement)
-        buf = arr.buffers()[1]
-        if buf is None:
-            return np.zeros(n, dtype=np.int64), valid
-        pairs = np.frombuffer(buf, dtype=np.int64, count=(arr.offset + n) * 2)
-        data = pairs.reshape(-1, 2)[arr.offset :, 0]
         return np.where(valid, data, 0), valid
-    if pa.types.is_date32(arr.type):
-        arr = arr.cast(pa.int32())
-    elif pa.types.is_timestamp(arr.type):
-        arr = arr.cast(pa.int64())
-    data = fixed_np(arr, dt.np_dtype)
     if not valid.all():
         data = np.where(valid, data, np.zeros((), dtype=dt.np_dtype))
     return np.ascontiguousarray(data), valid

@@ -437,6 +437,13 @@ CATALOG: Iterable[tuple] = (
     ("batch.padTimeNs", MetricKind.NANOS,
      "host time padding batches out to the pow-2 shape-bucket lattice "
      "capacity before H2D upload (spark.rapids.tpu.shapeBuckets.*)"),
+    ("batch.padStringPlanes", MetricKind.COUNTER,
+     "string planes padded for H2D upload, one per string column (or "
+     "list-of-string element plane) of each uploaded batch"),
+    ("batch.padStringPlanesFixedLen", MetricKind.COUNTER,
+     "of batch.padStringPlanes, those whose values all had one length and "
+     "no nulls (char(n) columns): filled by one strided copy of the value "
+     "buffer; the rest are right-padded by pyarrow a chunk of rows at a time"),
     ("mem.deviceBytesHighWatermark", MetricKind.WATERMARK,
      "peak registered spillable bytes on device, sampled at batch boundaries"),
     # mem/semaphore.py — admission control
