@@ -28,6 +28,10 @@ from ..plan.physical import Exec, ExecContext, PartitionSet
 from ..types import Schema, StringType, StructField
 from .tpu import val_to_column
 from .. import kernels as K
+from ..obs import metrics as obs_metrics
+
+_M_CALLS = obs_metrics.GLOBAL.counter("join.calls")
+_M_ROWS_OUT = obs_metrics.GLOBAL.counter("join.rowsOut")
 
 
 def _colocate_with(batch: DeviceBatch, anchor: DeviceBatch) -> DeviceBatch:
@@ -153,6 +157,8 @@ def _stream_probe_join(node, get_build, probe_thunk, phase1, phase2, jt,
             # graft: ok(host-sync: already on host — item of the single
             # windowed device_get above)
             total = int(total_dev)
+            _M_CALLS.add(1)
+            _M_ROWS_OUT.add(total)
             out_cap = bucket_capacity(max(total, 1))
             out, probe_matched, bmatch = phase2(
                 build,
@@ -745,13 +751,14 @@ def null_extend_batch(
     kernel (one compact + null-column splice per call, not eager ops)."""
     lf, rf = tuple(left_fields), tuple(right_fields)
     ro = None if right_ordinals is None else tuple(right_ordinals)
-    fn = K.kernel(
-        ("null_extend", out_schema, side, lf, rf, ro),
-        lambda: K.GuardedJit(
-            lambda b, k: _null_extend_impl(out_schema, b, k, side, lf, rf, ro)
-        ),
-    )
-    return fn(batch, keep)
+    def make():
+        def _join_null_extend(b, k):
+            return _null_extend_impl(out_schema, b, k, side, lf, rf, ro)
+
+        return _join_null_extend
+
+    kernel = K.jit_kernel(("null_extend", out_schema, side, lf, rf, ro), make)
+    return kernel(batch, keep)
 
 
 def _null_extend_impl(
@@ -795,8 +802,11 @@ def _null_column(f: StructField, cap: int) -> DeviceColumn:
         jnp.zeros(cap, bool),
     )
 
+# Each jitted function has a name of its own, so that a device trace reads
+# jit__join_bounds, jit__join_pairs, jit__join_cross_pairs and
+# jit__join_null_extend and not four times jit_fn.
 def _make_phase1(left_keys: tuple, right_keys: tuple):
-    def fn(build: DeviceBatch, probe: DeviceBatch):
+    def _join_bounds(build: DeviceBatch, probe: DeviceBatch):
         bctx = Ctx.for_device(build)
         pctx = Ctx.for_device(probe)
         bcols = [val_to_column(bctx, k.eval(bctx), k.data_type) for k in right_keys]
@@ -813,11 +823,11 @@ def _make_phase1(left_keys: tuple, right_keys: tuple):
         counts = upper - lower
         return build_order, lower, counts
 
-    return fn
+    return _join_bounds
 
 
 def _make_phase2(out_schema: Schema, right_ords: tuple, jt: str, residual):
-    def fn(
+    def _join_pairs(
             build: DeviceBatch,
             probe: DeviceBatch,
             build_order,
@@ -873,11 +883,11 @@ def _make_phase2(out_schema: Schema, right_ords: tuple, jt: str, residual):
             out = compact(out, live)
             return out, probe_matched, build_matched
 
-    return fn
+    return _join_pairs
 
 
 def _make_pair_kernel(out_schema: Schema, condition, jt: str):
-    def fn(lb: DeviceBatch, rb: DeviceBatch):
+    def _join_cross_pairs(lb: DeviceBatch, rb: DeviceBatch):
             n, m = lb.capacity, rb.capacity
             cap = n * m
             li = jnp.arange(cap, dtype=jnp.int32) // m
@@ -917,7 +927,7 @@ def _make_pair_kernel(out_schema: Schema, condition, jt: str):
             )
             return compact(out, live), left_matched, right_matched
 
-    return fn
+    return _join_cross_pairs
 
 
 class TpuCartesianProductExec(TpuBroadcastNestedLoopJoinExec):

@@ -5,15 +5,42 @@ and inlines its single value) and GpuInSet.scala (set membership compiled
 against a literal value set instead of an OR chain). TPC-DS leans on both
 (`where x in (select ...)`, `where y > (select avg ...)`).
 
-Execution model mirrors Spark's: subqueries run BEFORE the main query —
-the session's resolution pass (`TpuSession._resolve_subqueries`) executes
-each subquery plan through the full engine and replaces
+Two routes, chosen by what the plan shows, with no conf key.
+
+**As a join.** ``c IN (subquery)`` that is a top-level AND conjunct of a
+``Filter`` (SQL ``WHERE``/``HAVING``, ``DataFrame.filter``) is rewritten
+to a left-semi join on ``c = item`` before planning (plan/subquery.py, as
+Catalyst's RewritePredicateSubquery does, which is what the reference
+plugin then sees). A filter keeps a row only where its condition is TRUE,
+so NULL and FALSE are one there and the join is exact; the subquery runs
+inside the main plan and nothing of its result comes to the host.
+
+**Before the main query, as literals.** Everything else is resolved by the
+session's pass (`TpuSession._resolve_subqueries`), which executes the
+subquery plan through the full engine and replaces
 
     ScalarSubquery(plan)   → Literal(value)
-    InSubquery(c, plan)    → InSet(c, sorted result values)
+    InSubquery(c, plan)    → InSet(c, distinct result values)
 
-so the main query's kernels see only literals — no runtime plan nesting,
-nothing dynamic under jit.
+so the main query's kernels see only literals. For a scalar subquery this
+is Spark's model. For ``IN`` it is kept for exactly the shapes where the
+join would not be exact, because NULL is told apart from FALSE:
+
+- ``NOT IN`` (and ``NOT (c IN ...)``): three-valued, so a null-aware anti
+  join — one NULL in the subquery's result empties the answer, and a NULL
+  probe never passes. The plain left-anti join keeps both.
+- ``IN`` under ``OR``, ``NOT``, ``CASE``/``IF`` or any other expression
+  inside a filter: the other branch decides what a NULL or a FALSE does.
+- ``IN`` in a ``SELECT`` list or an aggregate's input: the value itself
+  (TRUE, FALSE or NULL) is the answer.
+- a probe and an item that are not one type, nor both numeric (the
+  planner widens numeric join keys as Catalyst does, and nothing else).
+
+On this route the result's values come to the host (``to_pylist`` and a
+Python loop, counted by ``subquery.hostValues``) and are compiled into the
+filter kernel as constants, which differ with every data set. Nothing but
+the subquery's own result size bounds that; there is no threshold. TPC-DS
+uses ``NOT IN`` and ``IN`` under ``OR`` only over small results.
 
 InSet's device path is ONE fused vectorized membership test: numerics
 binary-search a sorted constant array (`searchsorted`); strings compare
@@ -61,7 +88,8 @@ class ScalarSubquery(Expression):
 
 @dataclass(frozen=True)
 class InSubquery(Expression):
-    """``c IN (subquery)``; resolved to InSet before planning."""
+    """``c IN (subquery)``; a semi join (plan/subquery.py) or an InSet
+    (session) before planning, as the module docstring sets out."""
 
     c: Expression
     plan: object

@@ -214,8 +214,9 @@ class Column:
         return self.getItem(key)
 
     def isin(self, *values) -> "Column":
-        # a DataFrame argument is `x IN (subquery)` (GpuInSet via the
-        # session's subquery resolution); literal lists stay an In chain
+        # a DataFrame argument is `x IN (subquery)`: a left-semi join where
+        # it is a conjunct of a filter (plan/subquery.py), else GpuInSet via
+        # the session's subquery resolution; literal lists stay an In chain
         if len(values) == 1 and hasattr(values[0], "_plan"):
             from .expr.subquery import InSubquery
 

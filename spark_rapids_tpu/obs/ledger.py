@@ -9,6 +9,11 @@ nanoseconds per phase:
 
     ``parse_plan``   — analysis + physical planning + overrides
                        (``session._prepare_plan``)
+    ``subquery``     — waiting for a subquery that runs as a job of its
+                       own before planning (``session._resolve_subqueries``:
+                       scalar subqueries, and the IN-subqueries that are
+                       not planned as semi joins); carved out of
+                       ``parse_plan``, inside which it runs
     ``queue_wait``   — scheduler admission wait (from ``Admission``)
     ``compile``      — XLA first-touch trace+compile and pre-compilation
                        warms (``kernels.GuardedJit``)
@@ -57,6 +62,7 @@ from typing import Dict, Optional
 #: allowed but these are the documented decomposition)
 PHASES = (
     "parse_plan",
+    "subquery",
     "queue_wait",
     "compile",
     "h2d",

@@ -394,6 +394,25 @@ CATALOG: Iterable[tuple] = (
      "window kernel launches (one per merged partition batch)"),
     ("window.rowsCapacity", MetricKind.COUNTER,
      "summed row capacity of the merged batches the window kernel was given"),
+    # session.py / plan/subquery.py — how each IN (subquery) predicate ran
+    ("subquery.semiJoins", MetricKind.COUNTER,
+     "IN (subquery) predicates planned as left-semi joins "
+     "(plan/subquery.py), per prepared plan"),
+    ("subquery.hostValues", MetricKind.COUNTER,
+     "values of IN-subquery results brought to the host and inlined as an "
+     "InSet literal (TpuSession._resolve_subqueries: NOT IN, IN under "
+     "OR/NOT/CASE or in a SELECT list)"),
+    ("exchange.reused", MetricKind.COUNTER,
+     "exchanges replaced by a reference to an identical one of the same plan "
+     "(plan/reuse.py under spark.sql.exchange.reuse), per prepared plan"),
+    # exec/tpu_join.py — counted where the host already holds the numbers:
+    # the match totals it pulled to size each output batch
+    ("join.calls", MetricKind.COUNTER,
+     "pair-gather launches of the equi-join (one per probe batch)"),
+    ("join.rowsOut", MetricKind.COUNTER,
+     "key-matched pairs the equi-joins sized their output batches for, "
+     "before any residual condition; a semi or anti join counts the pairs "
+     "it examined"),
     # kernels.py key_sort_kernel — per launch of an aggregate, sort or window
     # kernel, static per kernel and input signature (no device sync)
     ("sort.keyPasses", MetricKind.COUNTER,

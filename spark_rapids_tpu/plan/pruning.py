@@ -17,7 +17,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Set
 
-from ..expr import Expression, UnresolvedAttribute
+from ..expr import Expression, UnresolvedAttribute, output_name
 from ..expr.base import BoundReference
 from ..types import Schema
 from . import logical as L
@@ -61,8 +61,18 @@ def prune_columns(plan: L.LogicalPlan, required: Optional[Set[str]] = None):
             )
         return L.FileScan(plan.paths, plan.file_format, sub, dict(plan.options))
     if isinstance(plan, L.Project):
-        child = prune_columns(plan.child, _names_of(plan.exprs))
-        return dataclasses.replace(plan, child=child)
+        exprs = plan.exprs
+        if required is not None:
+            # a projection carries only what is read above it (Catalyst's
+            # ColumnPruning on Project): the SQL compiler renames every
+            # column of a self-joined table, and a semi join's build side
+            # needs its keys alone (TPC-DS q94 broadcast all 34 columns of
+            # web_sales into its EXISTS)
+            kept = [e for e in exprs if output_name(e) in required]
+            if kept and len(kept) < len(exprs):
+                exprs = kept
+        child = prune_columns(plan.child, _names_of(exprs))
+        return dataclasses.replace(plan, exprs=exprs, child=child)
     if isinstance(plan, L.Aggregate):
         child = prune_columns(
             plan.child, _names_of(plan.grouping) | _names_of(plan.aggregates)
@@ -87,17 +97,17 @@ def prune_columns(plan: L.LogicalPlan, required: Optional[Set[str]] = None):
         # safe). Joins were previously unmodeled, which left e.g. TPC-H q3
         # dragging all 8 lineitem columns through filter + exchange + join
         # when 4 are referenced — every gather/upload pays per column.
-        need = None
-        if required is not None:
-            need = (
-                set(required)
-                | _names_of(plan.left_keys)
-                | _names_of(plan.right_keys)
-            )
-            if plan.residual is not None:
-                _expr_names(plan.residual, need)
+        own = _names_of(plan.left_keys) | _names_of(plan.right_keys)
+        if plan.residual is not None:
+            _expr_names(plan.residual, own)
+        need = None if required is None else set(required) | own
         lreq = None if need is None else need & set(plan.left.schema.names)
         rreq = None if need is None else need & set(plan.right.schema.names)
+        if plan.join_type in ("left_semi", "left_anti"):
+            # nothing of the build side is emitted: it needs its keys and
+            # what the residual reads, whatever is asked of the join (and
+            # where everything is: `required` names left columns only)
+            rreq = own & set(plan.right.schema.names)
         return dataclasses.replace(
             plan,
             left=prune_columns(plan.left, lreq),

@@ -25,6 +25,11 @@ from .plan.physical import Exec, ExecContext
 from .plan.planner import plan_physical
 from .types import Schema
 from .columnar.host import concat_batches
+from .obs import metrics as _obs_metrics
+
+_M_SUBQUERY_SEMI_JOINS = _obs_metrics.GLOBAL.counter("subquery.semiJoins")
+_M_SUBQUERY_HOST_VALUES = _obs_metrics.GLOBAL.counter("subquery.hostValues")
+_M_EXCHANGES_REUSED = _obs_metrics.GLOBAL.counter("exchange.reused")
 
 # threading.stack_size is process-global: EVERY set→spawn→restore window in
 # the engine (partition workers here, pipeline producers) shares this one
@@ -431,6 +436,10 @@ class TpuSession:
 
             ScalarSubquery(plan) → Literal(value)
             InSubquery(c, plan)  → InSet(c, distinct values)
+
+        An ``InSubquery`` that is a conjunct of a filter never gets here:
+        ``plan/subquery.py`` has made it a left-semi join (the shapes that
+        do get here are listed in ``expr/subquery.py``).
         """
         from .expr.base import Literal
         from .expr.subquery import InSet, InSubquery, ScalarSubquery
@@ -443,10 +452,15 @@ class TpuSession:
             multiproc_topology() at construction): the old save/restore of
             the shared conf let a concurrent query on another thread plan
             itself multiproc-off mid-subquery."""
-            if self._mp_topology[0]:
-                with self._single_process_scope():
-                    return self._execute(plan)
-            return self._execute(plan)
+            from .obs import ledger as obs_ledger
+
+            # the subquery is a job of its own with a ledger of its own;
+            # the main query waits for it here and says so
+            with obs_ledger.phase("subquery"):
+                if self._mp_topology[0]:
+                    with self._single_process_scope():
+                        return self._execute(plan)
+                return self._execute(plan)
 
         def fix(e):
             if isinstance(e, ScalarSubquery):
@@ -478,6 +492,7 @@ class TpuSession:
                         f"{tbl.num_columns}"
                     )
                 vals = tbl.column(0).to_pylist()
+                _M_SUBQUERY_HOST_VALUES.add(len(vals))
                 seen: set = set()
                 out = []
                 has_null = False
@@ -817,7 +832,11 @@ class TpuSession:
 
     def _prepare_plan_inner(self, lp: L.LogicalPlan):
         from .plan.pruning import prune_columns
+        from .plan.subquery import rewrite_in_subqueries
 
+        lp, semi_joins = rewrite_in_subqueries(lp)
+        if semi_joins:
+            _M_SUBQUERY_SEMI_JOINS.add(semi_joins)
         lp = self._resolve_cached(lp)
         lp = self._resolve_subqueries(lp)
         if cfg.UDF_COMPILER_ENABLED.get(self.conf):
@@ -864,6 +883,7 @@ class TpuSession:
             from .plan.reuse import reuse_exchanges
 
             final_plan, self._last_reused_exchanges = reuse_exchanges(final_plan)
+            _M_EXCHANGES_REUSED.add(self._last_reused_exchanges)
         else:
             self._last_reused_exchanges = 0
         self._last_plan = final_plan

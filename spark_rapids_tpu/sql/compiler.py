@@ -19,7 +19,8 @@ Design (the standalone slice of Catalyst's analyzer this engine needs):
   existing GroupedData grouping-sets machinery; ``grouping(x)`` reads the
   grouping-id bit.
 - **Subqueries**: uncorrelated scalar/IN become ScalarSubquery/InSubquery
-  (resolved by the session before planning). Correlated EXISTS / IN /
+  (the session plans an IN that is a WHERE conjunct as a left-semi join and
+  resolves the rest to literals before planning). Correlated EXISTS / IN /
   scalar-aggregate subqueries are decorrelated into left_semi / left_anti /
   grouped-join rewrites — the same relational rewrites the hand-written
   TPC-H translations use (tpch/queries.py), applied mechanically.
@@ -961,7 +962,9 @@ class Compiler:
     def _compile_in_query(
         self, rel: Rel, probe: Node, q: QueryExpr, negated: bool, views, scope
     ) -> Rel:
-        # uncorrelated → InSubquery expression (session resolves to InSet)
+        # uncorrelated → a filter on an InSubquery expression: as a WHERE
+        # conjunct the session plans it as a left-semi join
+        # (plan/subquery.py), negated it resolves to an InSet
         if not self._is_correlated(q, views, scope):
             inner = self.compile_query(q, views, outer=None).df
             probe_c = self.compile_expr(probe, scope)

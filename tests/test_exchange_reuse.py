@@ -44,10 +44,16 @@ def test_self_join_aggregate_reuses_exchange(monkeypatch):
 
     monkeypatch.setattr(TpuShuffleExchangeExec, "_execute_impl", counting)
 
+    from spark_rapids_tpu.obs import metrics
+
     t = _table()
     s = tpu_session()
+    reused_before = metrics.GLOBAL.snapshot()["exchange.reused"]
     rows_t = _self_join_agg(s, t).collect()
     assert s._last_reused_exchanges >= 1, "no exchange was deduplicated"
+    # the process-wide counter says the same
+    reused = metrics.GLOBAL.snapshot()["exchange.reused"] - reused_before
+    assert reused == s._last_reused_exchanges
     # the shared node's pipeline ran exactly once
     assert len(calls) == len(set(calls)), (
         "a reused exchange executed its pipeline more than once"
