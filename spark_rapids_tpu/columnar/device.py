@@ -602,7 +602,7 @@ def device_to_host_speculative(batch: DeviceBatch):
     if any(c.children is not None for c in batch.columns):
         return None, None
     from .. import kernels as K
-    from ..ops.gather import gather_column
+    from ..ops.gather import gather_columns
 
     widths = tuple(
         c.data.shape[1] if c.data.ndim == 2 else None for c in batch.columns
@@ -611,7 +611,7 @@ def device_to_host_speculative(batch: DeviceBatch):
     def make():
         def run(b: DeviceBatch):
             idx = jnp.arange(SPEC_PULL_PREFIX, dtype=jnp.int32)
-            cols = [gather_column(c, idx) for c in b.columns]
+            cols = gather_columns(b.columns, idx)
             nb = DeviceBatch(
                 b.schema, cols, jnp.minimum(b.num_rows, SPEC_PULL_PREFIX)
             )

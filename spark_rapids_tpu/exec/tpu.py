@@ -38,7 +38,7 @@ from ..expr.misc import contains_task_dependent
 from . import task
 from ..ops.aggregate import group_aggregate
 from ..ops.concat import concat_device
-from ..ops.gather import bulk_shrink, compact, gather_batch, gather_column
+from ..ops.gather import bulk_shrink, compact, gather_batch, gather_columns
 from ..ops.hash import murmur3_rows, partition_ids
 from ..ops.sortkeys import packed_key, packed_sort
 from ..plan.logical import SortOrder
@@ -963,7 +963,7 @@ class TpuHashAggregateExec(Exec):
             return _width
 
         key = ("agg_width", grouping, child_schema, pre_filter, has_nans)
-        return K.key_sort_kernel(key, make)
+        return K.counted_kernel(key, make)
 
     def _fused_child(self) -> tuple:
         """(effective child, fused pre_filter) — the filter-fusion decision,
@@ -1208,7 +1208,7 @@ def aggregate_kernel(
         has_nans,
         collect_width,
     )
-    return K.key_sort_kernel(key, make)
+    return K.counted_kernel(key, make)
 
 
 def aggregate_merge_kernel(
@@ -1238,7 +1238,7 @@ def aggregate_merge_kernel(
 
         return _m
 
-    return K.key_sort_kernel(
+    return K.counted_kernel(
         ("agg_merge", grouping, agg_fns, out_schema, has_nans), make
     )
 
@@ -1370,7 +1370,7 @@ def device_sort_fn(order: List[SortOrder]):
 
         return _sort
 
-    return K.key_sort_kernel(("sort", _order_key(order)), make)
+    return K.counted_kernel(("sort", _order_key(order)), make)
 
 
 def device_merge_fn(order: List[SortOrder]):
@@ -1724,7 +1724,7 @@ class TpuGenerateExec(Exec):
                 r = jnp.clip(r, 0, batch.capacity - 1)
                 prev = jnp.where(r > 0, coff[jnp.clip(r - 1, 0, None)], 0)
                 p = (j - prev).astype(jnp.int32)
-                out_cols = [gather_column(col, r, live) for col in batch.columns]
+                out_cols = gather_columns(batch.columns, r, live)
                 if position:
                     from ..types import INT
 
@@ -1974,7 +1974,7 @@ class TpuShuffleExchangeExec(Exec):
 
             return (
                 "hash",
-                K.jit_kernel(
+                K.counted_kernel(
                     ("exchange_hash", keys, nparts, pre_filter), make_hash
                 ),
             )
@@ -1993,7 +1993,7 @@ class TpuShuffleExchangeExec(Exec):
 
             return (
                 "roundrobin",
-                K.jit_kernel(("exchange_rr", nparts, pre_filter), make_rr),
+                K.counted_kernel(("exchange_rr", nparts, pre_filter), make_rr),
             )
 
         if isinstance(part, RangePartitioning):
@@ -2034,7 +2034,7 @@ class TpuShuffleExchangeExec(Exec):
                 "range",
                 (
                     words_jit,
-                    K.jit_kernel(
+                    K.counted_kernel(
                         ("exchange_range_slice", nparts, pre_filter),
                         make_range,
                     ),

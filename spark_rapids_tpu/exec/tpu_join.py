@@ -20,9 +20,9 @@ import jax.numpy as jnp
 
 from ..columnar.device import DeviceBatch, DeviceColumn, bucket_capacity, dc_replace, empty_batch
 from ..expr import Expression, bind
-from ..expr.base import Ctx, Val
+from ..expr.base import BoundReference, Ctx, Val
 from ..ops.concat import concat_device
-from ..ops.gather import compact, gather_column, shrink_one
+from ..ops.gather import compact, gather_columns, shrink_one
 from ..ops.join import gather_pairs, join_bounds, join_output_schema, pad_string_column
 from ..plan.physical import Exec, ExecContext, PartitionSet
 from ..types import Schema, StringType, StructField
@@ -247,7 +247,7 @@ class TpuShuffledHashJoinExec(Exec):
             residual = bind(residual, pair_schema)
 
         key = ("join_p2", jt, residual, right_ords, out_schema)
-        return K.jit_kernel(
+        return K.counted_kernel(
             key, lambda: _make_phase2(out_schema, right_ords, jt, residual)
         )
 
@@ -616,7 +616,7 @@ def _chunk_device_batch(db: DeviceBatch, rows: int):
     for lo in range(0, max(n, 1), rows):
         idx = jnp.arange(rows, dtype=jnp.int32) + lo
         live = idx < db.num_rows
-        cols = [gather_column(c, idx, live) for c in db.columns]
+        cols = gather_columns(db.columns, idx, live)
         yield DeviceBatch(
             db.schema,
             cols,
@@ -668,7 +668,7 @@ class TpuBroadcastNestedLoopJoinExec(Exec):
         condition = self.condition
         jt = self.join_type
         key = ("join_pair", jt, condition, out_schema)
-        return K.jit_kernel(key, lambda: _make_pair_kernel(out_schema, condition, jt))
+        return K.counted_kernel(key, lambda: _make_pair_kernel(out_schema, condition, jt))
 
 
     def _null_extend(self, batch: DeviceBatch, keep: jax.Array, side: str) -> DeviceBatch:
@@ -826,7 +826,44 @@ def _make_phase1(left_keys: tuple, right_keys: tuple):
     return _join_bounds
 
 
+def _bound_ordinals(e, into: set) -> set:
+    """The input ordinals an expression reads (``BoundReference`` is the one
+    reader of ``Ctx.columns``)."""
+    if isinstance(e, BoundReference):
+        into.add(e.ordinal)
+    for c in e.children():
+        _bound_ordinals(c, into)
+    return into
+
+
+def _gather_read(cols, read, idx, idx_valid) -> list:
+    """``cols`` through ``idx``, ``None`` where an ordinal is not in ``read``."""
+    read = sorted(read)
+    out: list = [None] * len(cols)
+    for i, c in zip(read, gather_columns([cols[i] for i in read], idx, idx_valid)):
+        out[i] = c
+    return out
+
+
+def _gather_sides(left, right, li, ri, pair_live, condition_ords, semi, right_out):
+    """Both sides' columns at their pair indices. A stack is gathered whole,
+    so only what is read is handed over: the condition's columns, and unless
+    the join is a semi or anti join (which returns no pairs) the left side
+    and the ``right_out`` ordinals of the right."""
+    nl = len(left)
+    lread = {o for o in condition_ords if o < nl}
+    rread = {o - nl for o in condition_ords if o >= nl}
+    if not semi:
+        lread, rread = range(nl), rread | set(right_out)
+    return (
+        _gather_read(left, lread, li, pair_live),
+        _gather_read(right, rread, ri, pair_live),
+    )
+
+
 def _make_phase2(out_schema: Schema, right_ords: tuple, jt: str, residual):
+    residual_ords = set() if residual is None else _bound_ordinals(residual, set())
+
     def _join_pairs(
             build: DeviceBatch,
             probe: DeviceBatch,
@@ -839,15 +876,21 @@ def _make_phase2(out_schema: Schema, right_ords: tuple, jt: str, residual):
             probe_idx, build_idx, pair_live, total = gather_pairs(
                 build_order, lower, counts, probe.row_mask(), out_cap
             )
-            lcols = [gather_column(c, probe_idx, pair_live) for c in probe.columns]
-            rcols_all = [gather_column(c, build_idx, pair_live) for c in build.columns]
+            semi = jt in ("left_semi", "left_anti")
+            lcols, rcols_all = _gather_sides(
+                probe.columns, build.columns, probe_idx, build_idx, pair_live,
+                residual_ords, semi, right_ords,
+            )
             live = pair_live
             if residual is not None:
                 rctx = Ctx(
                     jnp,
                     out_cap,
                     True,
-                    [Val(c.data, c.validity, c.lengths) for c in lcols + rcols_all],
+                    [
+                        None if c is None else Val(c.data, c.validity, c.lengths)
+                        for c in lcols + rcols_all
+                    ],
                     total,
                 )
                 rv = residual.eval(rctx)
@@ -862,13 +905,12 @@ def _make_phase2(out_schema: Schema, right_ords: tuple, jt: str, residual):
             build_matched = (
                 jnp.zeros(nb, bool).at[jnp.where(live, build_idx, nb)].set(True, mode="drop")
             )
-            rcols = [rcols_all[i] for i in right_ords]
-            if jt in ("left_semi", "left_anti"):
+            if semi:
                 want = probe_matched if jt == "left_semi" else (
                     ~probe_matched & probe.row_mask()
                 )
                 return compact(probe, want), probe_matched, build_matched
-            cols = lcols + rcols
+            cols = lcols + [rcols_all[i] for i in right_ords]
             # num_rows = full capacity: live pairs are scattered across the
             # pair grid, so compact must see every slot (its keep mask is
             # intersected with row_mask)
@@ -887,21 +929,29 @@ def _make_phase2(out_schema: Schema, right_ords: tuple, jt: str, residual):
 
 
 def _make_pair_kernel(out_schema: Schema, condition, jt: str):
+    condition_ords = set() if condition is None else _bound_ordinals(condition, set())
+
     def _join_cross_pairs(lb: DeviceBatch, rb: DeviceBatch):
             n, m = lb.capacity, rb.capacity
             cap = n * m
             li = jnp.arange(cap, dtype=jnp.int32) // m
             ri = jnp.arange(cap, dtype=jnp.int32) % m
             pair_live = (li < lb.num_rows) & (ri < rb.num_rows)
-            lcols = [gather_column(c, li, pair_live) for c in lb.columns]
-            rcols = [gather_column(c, ri, pair_live) for c in rb.columns]
+            semi = jt in ("left_semi", "left_anti")
+            lcols, rcols = _gather_sides(
+                lb.columns, rb.columns, li, ri, pair_live,
+                condition_ords, semi, range(len(rb.columns)),
+            )
             live = pair_live
             if condition is not None:
                 cctx = Ctx(
                     jnp,
                     cap,
                     True,
-                    [Val(c.data, c.validity, c.lengths) for c in lcols + rcols],
+                    [
+                        None if c is None else Val(c.data, c.validity, c.lengths)
+                        for c in lcols + rcols
+                    ],
                     live.sum().astype(jnp.int32),
                 )
                 cv = condition.eval(cctx)
@@ -913,7 +963,7 @@ def _make_pair_kernel(out_schema: Schema, condition, jt: str):
             right_matched = (
                 jnp.zeros(m, bool).at[jnp.where(live, ri, m)].set(True, mode="drop")
             )
-            if jt in ("left_semi", "left_anti"):
+            if semi:
                 return None, left_matched, right_matched
             # num_rows = cap: live pairs are scattered over the [n x m] grid
             # and compact intersects its keep mask with row_mask

@@ -43,7 +43,7 @@ from ..expr.windows import (
     RowNumber,
 )
 from ..ops.concat import concat_device
-from ..ops.gather import gather_batch
+from ..ops.gather import gather_batch, gather_planes
 from ..ops.scan import segscan as _segscan
 from ..ops.sortkeys import packed_key, packed_sort, segment_starts
 from ..plan.physical import Exec, ExecContext, PartitionSet
@@ -111,7 +111,7 @@ class TpuWindowExec(Exec):
         from .. import kernels as K
 
         key = ("window", pkeys, orders, window_cols, out_schema, child_schema)
-        return K.key_sort_kernel(
+        return K.counted_kernel(
             key,
             lambda: _make_window_kernel(
                 pkeys, orders, window_cols, out_schema, child_schema
@@ -248,17 +248,19 @@ def _compute_window_column(
         k = fn.offset if isinstance(fn, Lead) else -fn.offset
         j = idx + k
         ok = (j >= seg_first) & (j <= seg_last) & live
-        safe = jnp.clip(j, 0, cap - 1)
+        there = gather_planes(
+            [col.data, col.validity, col.lengths], jnp.clip(j, 0, cap - 1)
+        )
         data = jnp.where(
             ok[:, None] if col.data.ndim == 2 else ok,
-            col.data[safe],
+            there[0],
             dcol.data,
         )
-        valid = jnp.where(ok, col.validity[safe], dcol.validity) & live
+        valid = jnp.where(ok, there[1], dcol.validity) & live
         lengths = None
         if col.lengths is not None:
             dlen = dcol.lengths if dcol.lengths is not None else jnp.zeros(cap, jnp.int32)
-            lengths = jnp.where(ok, col.lengths[safe], dlen)
+            lengths = jnp.where(ok, there[2], dlen)
         return DeviceColumn(we.data_type, data, valid, lengths)
 
     # ── aggregates over a frame ─────────────────────────────────────────
@@ -328,9 +330,11 @@ def _compute_window_column(
             jnp.zeros_like(pcnt[0]),
         )
         ok = ((hi_c - lo_c) > 0) & nonempty
-        safe = jnp.clip(pick, 0, cap - 1)
-        data_o = jnp.where(ok[:, None], col.data[safe], 0).astype(jnp.uint8)
-        len_o = jnp.where(ok, col.lengths[safe], 0).astype(jnp.int32)
+        data_o, len_o = gather_planes(
+            [col.data, col.lengths], jnp.clip(pick, 0, cap - 1)
+        )
+        data_o = jnp.where(ok[:, None], data_o, 0).astype(jnp.uint8)
+        len_o = jnp.where(ok, len_o, 0).astype(jnp.int32)
         return DeviceColumn(we.data_type, data_o, ok, len_o)
 
     if isinstance(fn, (Min, Max)):

@@ -48,6 +48,8 @@ _M_COMPILE_NS = obs_metrics.GLOBAL.timer("kernel.compileTimeNs")
 _M_COMPILE_HIST = obs_metrics.GLOBAL.histogram("kernel.compileHist")
 _M_KEY_PASSES = obs_metrics.GLOBAL.counter("sort.keyPasses")
 _M_KEY_PASSES_UNPACKED = obs_metrics.GLOBAL.counter("sort.keyPassesUnpacked")
+_M_GATHER_PLANES = obs_metrics.GLOBAL.counter("gather.planes")
+_M_GATHER_LAUNCHES = obs_metrics.GLOBAL.counter("gather.launches")
 
 
 def kernel(key: tuple, builder: Callable):
@@ -530,40 +532,50 @@ def jit_kernel(key: tuple, make_fn: Callable):
     return kernel(key, lambda: GuardedJit(make_fn()))
 
 
-class _KeyPassCounter:
-    """``on_launch`` hook of a kernel whose program sorts by packed keys (the
-    aggregate, sort and window kernels). Each launch adds the program's sort
-    passes to ``sort.keyPasses`` and what two passes a uint64 radix word
-    would have run to ``sort.keyPassesUnpacked``. Both are static per input
-    signature (key dtypes and string plane widths), so they are read once
-    per signature from an abstract trace — an executable loaded from the
-    store is never traced — and a launch costs a lookup and two counter
-    adds, no device sync."""
+class _LaunchCounter:
+    """``on_launch`` hook of a kernel whose program sorts by packed keys or
+    gathers planes (the aggregate, sort, window, join-pair, exchange-slice and
+    shrink kernels). Each launch adds the program's sort passes to
+    ``sort.keyPasses``, what two passes a uint64 radix word would have run to
+    ``sort.keyPassesUnpacked``, the planes it hands to ``ops/gather.py``'s
+    ``gather_planes`` to ``gather.planes`` and the gathers that issues to
+    ``gather.launches``. All are static per input signature (dtypes, plane
+    widths, capacities), so they are read once per signature from one
+    abstract trace — an executable loaded from the store is never traced —
+    and a launch costs a lookup and four counter adds, no device sync."""
 
-    __slots__ = ("_raw", "_passes")
+    __slots__ = ("_raw", "_passes", "_gathers")
 
     def __init__(self, raw):
         self._raw = raw
         self._passes: dict = {}
+        self._gathers: dict = {}
 
     def __call__(self, sig, args) -> None:
         passes = self._passes.get(sig)
         if passes is None:
+            from .ops.gather import counting_gathers
             from .ops.sortkeys import counting_passes
 
-            with counting_passes() as count:
+            with counting_passes() as count, counting_gathers() as gathers:
                 jax.eval_shape(self._raw, *args)
             passes = self._passes[sig] = tuple(count)
+            self._gathers[sig] = tuple(gathers)
+        planes, launches = self._gathers[sig]
         _M_KEY_PASSES.add(passes[0])
         _M_KEY_PASSES_UNPACKED.add(passes[1])
+        _M_GATHER_PLANES.add(planes)
+        _M_GATHER_LAUNCHES.add(launches)
 
 
-def key_sort_kernel(key: tuple, make_fn: Callable):
-    """``jit_kernel`` for a program that sorts by packed keys."""
+def counted_kernel(key: tuple, make_fn: Callable):
+    """``jit_kernel`` for a program that sorts by packed keys or gathers
+    planes: its launches feed the ``sort.keyPasses*`` and ``gather.*``
+    counters."""
 
     def build():
         raw = make_fn()
-        return GuardedJit(raw, on_launch=_KeyPassCounter(raw))
+        return GuardedJit(raw, on_launch=_LaunchCounter(raw))
 
     return kernel(key, build)
 

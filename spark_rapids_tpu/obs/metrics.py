@@ -413,7 +413,7 @@ CATALOG: Iterable[tuple] = (
      "key-matched pairs the equi-joins sized their output batches for, "
      "before any residual condition; a semi or anti join counts the pairs "
      "it examined"),
-    # kernels.py key_sort_kernel — per launch of an aggregate, sort or window
+    # kernels.py counted_kernel — per launch of an aggregate, sort or window
     # kernel, static per kernel and input signature (no device sync)
     ("sort.keyPasses", MetricKind.COUNTER,
      "sort passes run over packed key words (ops/sortkeys.py packed_sort: "
@@ -421,6 +421,13 @@ CATALOG: Iterable[tuple] = (
     ("sort.keyPassesUnpacked", MetricKind.COUNTER,
      "passes the same sorts would have run at two a uint64 radix word; "
      "over sort.keyPasses it is how far the packing engages"),
+    # the same hook, and the join-pair, exchange-slice and shrink kernels too
+    ("gather.planes", MetricKind.COUNTER,
+     "planes handed to ops/gather.py gather_planes (data, validity, lengths "
+     "and child planes that share one index)"),
+    ("gather.launches", MetricKind.COUNTER,
+     "gathers gather_planes issued for them, one a stack; gather.planes "
+     "over it is how far the stacking engages"),
     # cache/xla_store.py — the persistent XLA executable store
     ("cache.xla.hit", MetricKind.COUNTER,
      "compiled executables deserialized from the on-disk store instead "

@@ -25,9 +25,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..columnar.device import DeviceBatch, DeviceColumn
+from ..columnar.device import DeviceBatch, DeviceColumn, dc_replace
 from ..types import LONG, DoubleType, FloatType, StringType
-from .gather import gather_column
+from .gather import compact_permutation, gather_column, gather_columns, gather_planes
 from .scan import first_k_positions, seg_end_flags, segscan
 from .sortkeys import (
     column_radix_words,
@@ -213,44 +213,56 @@ def group_aggregate(
     end_pos = first_k_positions(ends)
 
     # representative keys: the first sorted row of each segment, gathered
-    # once from the unsorted columns (not at perm and again at start_pos)
+    # once from the unsorted columns (not at perm and again at start_pos),
+    # every key's planes in one call
     first_row = perm[start_pos]
-    out_keys: list[DeviceColumn] = []
-    for k in keys:
-        gk = gather_column(k, first_row, group_live)
-        out_keys.append(
-            DeviceColumn(
-                k.dtype,
-                _mask_data(gk.data, group_live),
-                gk.validity & group_live,
-                None if gk.lengths is None else jnp.where(group_live, gk.lengths, 0),
-            )
+    out_keys = [
+        DeviceColumn(
+            k.dtype,
+            _mask_data(gk.data, group_live),
+            gk.validity & group_live,
+            None if gk.lengths is None else jnp.where(group_live, gk.lengths, 0),
         )
+        for k, gk in zip(keys, gather_columns(keys, first_row, group_live))
+    ]
 
-    out_aggs: list[DeviceColumn] = []
+    # Every aggregate scans first; the planes they read at their segments'
+    # ends go through end_pos in ONE call (stacked: ops/gather.py), and then
+    # each aggregate is finished from its share of what came back.
+    at_end: list[jax.Array] = []
+    finish: list = []  # (function of an aggregate's gathered planes, their slice)
+
+    def defer(fn, *planes):
+        finish.append((fn, len(at_end), len(at_end) + len(planes)))
+        at_end.extend(planes)
+
     str_words_cache: dict = {}  # id(col) → ascending base words (min+max share)
-    for col, op in zip(agg_columns, ops):
-        sc = gather_column(col, perm)
+    # a column that feeds several aggregates is gathered once; a stack is
+    # gathered whole, so a count hands over the validity it reads and no more
+    read = [
+        DeviceColumn(c.dtype, None, c.validity) if op == "count" else c
+        for c, op in zip(agg_columns, ops)
+    ]
+    for col, sc, op in zip(agg_columns, gather_columns(read, perm), ops):
         v = sc.validity & live
         is_str = isinstance(col.dtype, StringType)
         if op in ("collect_list", "collect_set"):
-            out_aggs.append(
-                _group_collect(
-                    op,
-                    col,
-                    sc,
-                    keys,
-                    row_mask,
-                    n_live,
-                    live,
-                    starts,
-                    end_pos,
-                    group_live,
-                    collect_width,
-                    cap,
-                    has_nans,
-                )
+            collected = _group_collect(
+                op,
+                col,
+                sc,
+                keys,
+                row_mask,
+                n_live,
+                live,
+                starts,
+                end_pos,
+                group_live,
+                collect_width,
+                cap,
+                has_nans,
             )
+            defer(lambda collected=collected: collected)
             continue
         if is_str and op in ("min", "max"):
             # string min/max: lexicographic arg-scan over the sortable word
@@ -262,59 +274,74 @@ def group_aggregate(
                 str_words_cache[id(col)] = base
             vwords = _string_value_words(base, v, op == "min")
             pickrow = _seg_arglexmin(vwords, starts, idx)
-            gpick = pickrow[end_pos]
-            any_v = (segscan(v.astype(jnp.int32), starts, jnp.add) > 0)[end_pos]
-            ok = any_v & group_live
-            safe = jnp.clip(gpick, 0, cap - 1)
-            data = jnp.where(ok[:, None], sc.data[safe], 0).astype(jnp.uint8)
-            lengths = jnp.where(ok, sc.lengths[safe], 0).astype(jnp.int32)
-            out_aggs.append(DeviceColumn(col.dtype, data, ok, lengths))
+            any_seen = segscan(v.astype(jnp.int32), starts, jnp.add) > 0
+
+            def picked_string(gpick, any_v, sc=sc, col=col):
+                ok = any_v & group_live
+                data, lengths = gather_planes(
+                    [sc.data, sc.lengths], jnp.clip(gpick, 0, cap - 1)
+                )
+                data = jnp.where(ok[:, None], data, 0).astype(jnp.uint8)
+                lengths = jnp.where(ok, lengths, 0).astype(jnp.int32)
+                return DeviceColumn(col.dtype, data, ok, lengths)
+
+            defer(picked_string, pickrow, any_seen)
             continue
         scan_vals, scan_valid, pick = _scan_reduce(op, sc.data, v, starts, idx, cap)
         if pick is not None:
             # first/last: gather the picked row's value per group
-            gpick = scan_vals[end_pos]  # pick at each segment's end
-            ok = (gpick != _BIG) & (gpick >= 0) & group_live
-            safe = jnp.clip(gpick, 0, cap - 1)
-            data = sc.data[safe]
-            valid_out = sc.validity[safe] & ok
-            lengths = sc.lengths[safe] if is_str else None
-            if data.ndim == 2:
-                data = jnp.where(ok[:, None], data, 0)
-            else:
-                data = jnp.where(ok, data, jnp.zeros_like(data))
-            out_aggs.append(DeviceColumn(col.dtype, data, valid_out, lengths))
+
+            def picked(gpick, sc=sc, col=col):  # the pick at each segment's end
+                ok = (gpick != _BIG) & (gpick >= 0) & group_live
+                data, valid_out, lengths = gather_planes(
+                    [sc.data, sc.validity, sc.lengths], jnp.clip(gpick, 0, cap - 1)
+                )
+                if data.ndim == 2:
+                    data = jnp.where(ok[:, None], data, 0)
+                else:
+                    data = jnp.where(ok, data, jnp.zeros_like(data))
+                return DeviceColumn(col.dtype, data, valid_out & ok, lengths)
+
+            defer(picked, scan_vals)
             continue
         # count only reads validity, so string inputs are fine there
         assert not (is_str and op != "count"), (
             f"string op {op} requires an index-pick"
         )
-        data = scan_vals[end_pos]
-        valid_out = scan_valid[end_pos] & group_live
+        nan_flags = []
         if (
             op in ("min", "max")
             and jnp.issubdtype(sc.data.dtype, jnp.floating)
             and has_nans
         ):
-            had_nan = _had_nan_scan(sc.data, v, starts)[end_pos]
-            if op == "max":
-                data = jnp.where(had_nan, jnp.nan, data)
-            else:
+            nan_flags.append(_had_nan_scan(sc.data, v, starts))
+            if op == "min":
                 # min is NaN only when EVERY valid value was NaN — a real
                 # +inf minimum alongside a NaN must stay +inf (NaN greatest)
-                has_nonnan = (
+                nan_flags.append(
                     segscan(
                         (v & ~jnp.isnan(sc.data)).astype(jnp.int32), starts, jnp.add
                     )
                     > 0
-                )[end_pos]
+                )
+
+        def reduced(data, valid_out, *nans, op=op, col=col):
+            valid_out = valid_out & group_live
+            if len(nans) == 1:
+                data = jnp.where(nans[0], jnp.nan, data)
+            elif nans:
+                had_nan, has_nonnan = nans
                 data = jnp.where(had_nan & ~has_nonnan, jnp.nan, data)
-        if op == "count":
-            valid_out = group_live  # count is never null
-        data = _mask_data(data, group_live)
-        # count's output is a LONG regardless of the input column's type
-        out_dtype = LONG if op == "count" else col.dtype
-        out_aggs.append(DeviceColumn(out_dtype, data, valid_out, None))
+            if op == "count":
+                valid_out = group_live  # count is never null
+            data = _mask_data(data, group_live)
+            # count's output is a LONG regardless of the input column's type
+            out_dtype = LONG if op == "count" else col.dtype
+            return DeviceColumn(out_dtype, data, valid_out, None)
+
+        defer(reduced, scan_vals, scan_valid, *nan_flags)
+    gathered = gather_planes(at_end, end_pos)
+    out_aggs = [fn(*gathered[lo:hi]) for fn, lo, hi in finish]
     return out_keys, out_aggs, num_groups
 
 
@@ -392,8 +419,6 @@ def _group_collect(
     kc = segscan(keep.astype(jnp.int32), use_starts, jnp.add)[use_end_pos]
     kc = jnp.where(group_live, kc, 0).astype(jnp.int32)
     # kept rows to the front, (group, order) sequence preserved
-    from .gather import compact_permutation
-
     kept = gather_column(use_sc, compact_permutation(keep))
     offs = jnp.concatenate(
         [jnp.zeros(1, jnp.int32), jnp.cumsum(kc)[:-1].astype(jnp.int32)]
@@ -403,10 +428,9 @@ def _group_collect(
     elem_live = (j < kc[:, None]) & group_live[:, None]
     safe = jnp.clip(gidx, 0, cap - 1)
     if isinstance(col.dtype, StringType):
-        edata = jnp.where(
-            elem_live[:, :, None], kept.data[safe], 0
-        ).astype(jnp.uint8)
-        elengths = jnp.where(elem_live, kept.lengths[safe], 0).astype(jnp.int32)
+        edata, elengths = gather_planes([kept.data, kept.lengths], safe)
+        edata = jnp.where(elem_live[:, :, None], edata, 0).astype(jnp.uint8)
+        elengths = jnp.where(elem_live, elengths, 0).astype(jnp.int32)
         elem = DeviceColumn(col.dtype, edata, elem_live, elengths)
     else:
         edata = jnp.where(elem_live, kept.data[safe], jnp.zeros((), kept.data.dtype))
@@ -472,31 +496,27 @@ def _ungrouped_aggregate(
                 vcol = _normalize_float(col, has_nans)
                 key2 = packed_key([vcol], valid, nulls_firsts=[False])
                 perm2 = packed_sort(key2)
-                svals = gather_column(vcol, perm2)
-                v2 = valid[perm2]
-                # valid rows sort first: among them a new value starts a run
-                keep = segment_starts(key2.sorted_words(perm2), v2)
             else:
-                from .gather import compact_permutation
-
+                vcol = col
                 perm2 = compact_permutation(valid)
-                svals = gather_column(col, perm2)
-                keep = valid[perm2]
-            from .gather import compact_permutation as _cperm
-
-            kept = gather_column(svals, _cperm(keep))
+            # the live validity rides with the column's planes
+            svals = gather_column(dc_replace(vcol, validity=valid), perm2)
+            keep = svals.validity
+            if op == "collect_set":
+                # valid rows sort first: among them a new value starts a run
+                keep = segment_starts(key2.sorted_words(perm2), keep)
+            kept = gather_column(svals, compact_permutation(keep))
             kcount = keep.sum().astype(jnp.int32)
             jW = jnp.arange(W, dtype=jnp.int32)
             elem_live0 = jW < kcount  # [W]
             safeW = jnp.clip(jW, 0, cap - 1)
             if is_str:
-                row0 = jnp.where(
-                    elem_live0[:, None], kept.data[safeW], 0
-                ).astype(jnp.uint8)
+                row0, len0 = gather_planes([kept.data, kept.lengths], safeW)
+                row0 = jnp.where(elem_live0[:, None], row0, 0).astype(jnp.uint8)
                 edata = jnp.where(one_live[:, None, None], row0[None], 0)
                 elengths = jnp.where(
                     one_live[:, None],
-                    jnp.where(elem_live0, kept.lengths[safeW], 0)[None, :],
+                    jnp.where(elem_live0, len0, 0)[None, :],
                     0,
                 ).astype(jnp.int32)
                 elem = DeviceColumn(

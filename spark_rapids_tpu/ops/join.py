@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from ..columnar.device import DeviceBatch, DeviceColumn
 from ..ops.aggregate import _normalize_float
+from ..ops.gather import gather_planes
 from ..ops.sortkeys import column_radix_words, sort_permutation
 from ..types import StringType
 
@@ -109,10 +110,9 @@ def join_bounds(
         perm = sort_permutation(
             keys + [flags.astype(jnp.uint64)], None, live_first=False
         )
-        sflags, ssrc = flags[perm], src[perm]
-        is_build = (
-            (sflags == 0) if not probe_first else (sflags == 1)
-        )
+        # a build row's source is -1: one gather tells the sides apart
+        ssrc = src[perm]
+        is_build = ssrc < 0
         nbefore = jnp.cumsum(is_build.astype(jnp.int32)) - is_build.astype(jnp.int32)
         # scatter each probe row's build-count back to its original position
         is_probe = ~is_build
@@ -148,8 +148,8 @@ def gather_pairs(
     ended = jnp.zeros(out_cap, jnp.int32).at[ends].add(1, mode="drop")
     probe_idx = jnp.cumsum(ended)
     probe_idx = jnp.clip(probe_idx, 0, lower.shape[0] - 1)
-    within = j - offsets[probe_idx]
-    sorted_pos = lower[probe_idx] + within
+    first_slot, first_match = gather_planes([offsets, lower], probe_idx)
+    sorted_pos = first_match + (j - first_slot)
     sorted_pos = jnp.clip(sorted_pos, 0, build_order.shape[0] - 1)
     build_idx = build_order[sorted_pos]
     pair_live = j < total

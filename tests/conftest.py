@@ -191,12 +191,52 @@ def _bound_jit_code_size():
     isolation). Real sessions never accumulate hundreds of distinct query
     shapes, and the TPU backend doesn't use the LLVM JIT at all."""
     yield
+    _release_jit_code()
+
+
+def _release_jit_code():
     import jax
 
     from spark_rapids_tpu import kernels as K
 
     K.clear()
     jax.clear_caches()
+
+
+def _memory_mappings() -> int:
+    try:
+        with open("/proc/self/maps", "rb") as f:
+            return sum(1 for _ in f)
+    except OSError:
+        return 0
+
+
+def _mappings_allowed() -> int:
+    try:
+        with open("/proc/sys/vm/max_map_count") as f:
+            return int(f.read())
+    except (OSError, ValueError):
+        return 65530
+
+
+@pytest.fixture(autouse=True)
+def _bound_jit_mappings():
+    """Release compiled XLA:CPU executables inside a long module too, once
+    the process holds half the memory mappings the kernel allows it.
+
+    Every executable the CPU backend's JIT loads keeps mappings of its own
+    (2,500 to 3,500 a TPC-DS query); at ``vm.max_map_count`` (65,530) the
+    next ``mmap`` fails and the process dies inside backend_compile_and_load.
+    ``test_tpcds.py`` compiles 99 queries into one process: 17 of them hold
+    42,000 mappings, and 62,000 since the planes of a batch are stacked
+    (ops/gather.py), whose programs hold more, smaller kernels on this
+    backend. The TPU backend loads no such code."""
+    yield
+    if _memory_mappings() > _mappings_allowed() // 2:
+        _release_jit_code()
+        import gc
+
+        gc.collect()
 
 
 #: tier-1 suites that exercise the engine's real multi-thread interleavings

@@ -434,7 +434,7 @@ def test_key_passes_counted_per_launch():
                                        [b.columns[2]], ["sum"])
         return _aggregate
 
-    kernel = K.key_sort_kernel(("test_key_passes_counted_per_launch",), make)
+    kernel = K.counted_kernel(("test_key_passes_counted_per_launch",), make)
     before = _pass_counters()
     kernel(batch)
     kernel(batch)
