@@ -226,13 +226,14 @@ class TpuShuffledHashJoinExec(Exec):
 
     # ── kernels ─────────────────────────────────────────────────────────
     def _phase1(self):
-        """counts per probe row (+ build order/lower for phase 2)."""
+        """counts per probe row (+ build order/lower for phase 2). Its one
+        packed sort feeds ``sort.keyPasses``."""
         left_keys, right_keys = tuple(self.left_keys), tuple(self.right_keys)
 
         def make():
             return _make_phase1(left_keys, right_keys)
 
-        return K.jit_kernel(("join_p1", left_keys, right_keys), make)
+        return K.counted_kernel(("join_p1", left_keys, right_keys), make)
     def _phase2(self):
         """Gather matched pairs into a static-capacity output batch."""
         out_schema = self._schema

@@ -535,8 +535,8 @@ def jit_kernel(key: tuple, make_fn: Callable):
 
 class _LaunchCounter:
     """``on_launch`` hook of a kernel whose program sorts by packed keys or
-    gathers planes (the aggregate, sort, window, join-pair, exchange-slice and
-    shrink kernels). Each launch adds the program's sort passes to
+    gathers planes (the aggregate, sort, window, join phase-1, join-pair,
+    exchange-slice and shrink kernels). Each launch adds the program's sort passes to
     ``sort.keyPasses``, what two passes a uint64 radix word would have run to
     ``sort.keyPassesUnpacked``, the planes it hands to ``ops/gather.py``'s
     ``gather_planes`` to ``gather.planes``, the gathers that issues to

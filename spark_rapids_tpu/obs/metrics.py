@@ -413,14 +413,16 @@ CATALOG: Iterable[tuple] = (
      "key-matched pairs the equi-joins sized their output batches for, "
      "before any residual condition; a semi or anti join counts the pairs "
      "it examined"),
-    # kernels.py counted_kernel — per launch of an aggregate, sort or window
-    # kernel, static per kernel and input signature (no device sync)
+    # kernels.py counted_kernel — per launch of an aggregate, sort, window or
+    # join phase-1 kernel, static per kernel and input signature (no device
+    # sync)
     ("sort.keyPasses", MetricKind.COUNTER,
      "sort passes run over packed key words (ops/sortkeys.py packed_sort: "
      "one stable single-key uint32 pass a word)"),
     ("sort.keyPassesUnpacked", MetricKind.COUNTER,
-     "passes the same sorts would have run at two a uint64 radix word; "
-     "over sort.keyPasses it is how far the packing engages"),
+     "passes the same sorts would have run at two a uint64 radix word (for "
+     "a join's phase 1, its three former sorts); over sort.keyPasses it is "
+     "how far the packing engages"),
     # the same hook, and the join-pair, exchange-slice and shrink kernels too
     ("gather.planes", MetricKind.COUNTER,
      "planes handed to ops/gather.py gather_planes (data, validity, lengths "

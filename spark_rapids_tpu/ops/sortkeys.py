@@ -15,8 +15,8 @@ custom comparators, static shapes. Two encodings of the same order:
   that is known to be zero.
 * **uint64 radix words** (``column_radix_words``): one or more whole words a
   column, comparable across columns of different integer widths. What the
-  join, the out-of-core merge, the range bounds and the string min/max
-  arg-scan compare.
+  out-of-core merge, the TopN prefilter, the range bounds and the string
+  min/max arg-scan compare.
 
 Orderings implemented to Spark's spec:
 * NULLs first/last via a leading validity word
@@ -82,8 +82,8 @@ def column_radix_words(
 
     ``value_only`` omits the standalone validity word for callers that
     handle nulls themselves AND keeps the classic widened-to-64-bit
-    encoding: the join compares words across columns of DIFFERENT integer
-    widths, which only works when every width shares one encoding. Default
+    encoding, so words compare across columns of DIFFERENT integer widths,
+    which only works when every width shares one encoding. Default
     (sort) callers get the packed layout for sub-64-bit types — validity
     folded into bit 63 of the single value word — so callers must never
     assume word[0] is a validity word; use this flag instead of slicing."""
@@ -145,26 +145,6 @@ def column_radix_words(
     if value_only:
         return words
     return [vw] + words
-
-
-def sort_permutation(
-    words: list[jax.Array],
-    row_mask: jax.Array,
-    live_first: bool = True,
-) -> jax.Array:
-    """Stable sort permutation over uint64 radix words; padding rows sort
-    last. Each 64-bit word goes as two 32-bit passes of ``_radix_passes``:
-    64-bit types are emulated on the TPU, and the one embedded sort compiles
-    about three times faster on a uint32 key. Callers whose key is theirs
-    alone (sort, group-by, window) run ``packed_key`` instead."""
-    halves = []  # most-significant first
-    if live_first:
-        halves.append(jnp.where(row_mask, jnp.uint32(0), jnp.uint32(1)))
-    for w in words:
-        w = w.astype(jnp.uint64)
-        halves.append((w >> jnp.uint64(32)).astype(jnp.uint32))
-        halves.append(w.astype(jnp.uint32))
-    return _radix_passes(jnp.stack(halves))
 
 
 def _radix_passes(keys: jax.Array) -> jax.Array:
@@ -253,12 +233,13 @@ def column_key_fields(
     return fields
 
 
-def unpacked_key_words(col: DeviceColumn) -> int:
-    """How many uint64 words ``column_radix_words`` makes of this column:
-    two sort passes each. Static: the dtype and the plane width."""
+def unpacked_key_words(col: DeviceColumn, value_only: bool = False) -> int:
+    """How many uint64 words ``column_radix_words`` makes of this column,
+    with or without ``value_only``: two sort passes each. Static: the dtype
+    and the plane width."""
     if isinstance(col.dtype, StringType):
-        return 1 + (col.data.shape[1] + 7) // 8 + 1
-    return 1 if col.dtype.np_dtype.itemsize <= 4 else 2
+        return (not value_only) + (col.data.shape[1] + 7) // 8 + 1
+    return 1 if value_only or col.dtype.np_dtype.itemsize <= 4 else 2
 
 
 def pack_fields(fields: list[tuple[jax.Array, int]]) -> list[jax.Array]:
@@ -314,7 +295,7 @@ def packed_key(
     nulls_firsts: list[bool] | None = None,
 ) -> PackedKey:
     """The key over ``columns`` with a leading live flag (padding rows sort
-    last): the order of ``sort_permutation`` over every column's
+    last): the order of a stable sort by every column's
     ``column_radix_words``, in as few words as its bits need."""
     fields = [(jnp.where(row_mask, jnp.uint32(0), jnp.uint32(1)), 1)]
     ends, unpacked = [], 1
